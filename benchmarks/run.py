@@ -47,6 +47,9 @@ def main() -> None:
                          "(0 disables; default 1800)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (
         fig5_smoke,
         kernel_bench,

@@ -25,7 +25,6 @@ the scalar subset call exactly (including the subset-local spread column).
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 
 import jax
@@ -33,35 +32,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.dispatch import record_dispatch
-
-try:  # pragma: no cover - present on every supported JAX
-    from jax.experimental import enable_x64 as _enable_x64
-except ImportError:  # pragma: no cover
-    _enable_x64 = None
-
-
-def _x64_context():
-    """The greedy compares float64 marginal utilities; run in x64 so the
-    bit-parity contract with the numpy reference holds."""
-    if _enable_x64 is None:
-        if not jax.config.jax_enable_x64:
-            # Without x64 the float64 inputs would silently downcast and
-            # the greedy could round differently from the numpy reference
-            # — refuse rather than break the parity contract quietly.
-            raise RuntimeError(
-                "batched Lookahead needs float64: this JAX has no "
-                "jax.experimental.enable_x64 and jax_enable_x64 is off; "
-                "enable x64 or use CacheController(backend='numpy')")
-        return contextlib.nullcontext()
-    return _enable_x64()
+from repro.core.x64 import x64_context
 
 
 def _resolve_backend(backend):
-    """``None`` -> the platform default: the Pallas kernel where it lowers
-    natively (TPU), the batched while_loop elsewhere (interpret-mode Pallas
-    on CPU is a correctness harness, not a fast path)."""
+    """``None`` -> the batched while_loop on every platform: it is the path
+    the parity tests pin.  The Pallas kernel runs only when asked for by
+    name (it does not lower for a TPU yet; off-TPU it runs interpreted)."""
     if backend is None:
-        backend = "pallas" if jax.default_backend() == "tpu" else "jax"
+        backend = "jax"
     if backend not in ("jax", "pallas"):
         raise ValueError(f"unknown lookahead backend {backend!r}")
     return backend
@@ -315,7 +294,7 @@ def lookahead_allocate(
     _validate(curves, total_units, mus)
     B, n, _ = flat.shape
     record_dispatch()
-    with _x64_context():
+    with x64_context():
         out = _greedy_core(
             jnp.asarray(flat, dtype=jnp.float64),
             jnp.asarray(mus),
@@ -392,7 +371,7 @@ def lookahead_allocate_grouped(
     backend = _resolve_backend(backend)
     fn = _compiled_grouped(tuple(u for _, u, _m in prepared), backend)
     record_dispatch()
-    with _x64_context():
+    with x64_context():
         outs = fn(tuple((jnp.asarray(c, dtype=jnp.float64), jnp.asarray(m))
                         for c, _u, m in prepared))
         outs = [np.asarray(o) for o in outs]
@@ -425,7 +404,7 @@ def lookahead_allocate_masked(
     # the spread key reads.
     remaining = total_units - mus * (n - act.sum(axis=-1))
     record_dispatch()
-    with _x64_context():
+    with x64_context():
         out = _greedy_core(
             jnp.asarray(flat, dtype=jnp.float64),
             jnp.asarray(mus),

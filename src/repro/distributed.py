@@ -14,58 +14,30 @@ import threading
 from typing import Optional, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 _state = threading.local()
 
 
-def axis_types_kwargs(n_axes: int) -> dict:
-    """Version shim: ``jax.sharding.AxisType`` landed after 0.4.x.
-
-    On new JAX, ``jax.make_mesh`` wants explicit axis types; on old JAX the
-    attribute (and the ``axis_types`` kwarg) does not exist.  Returns the
-    kwargs dict to splat into ``jax.make_mesh``.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_mesh(shape, axis_names, devices=None) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types on JAX versions that have them.
+    """``jax.make_mesh`` with Auto axis types.
 
     ``devices`` restricts the mesh to a device subset (the shard-count
     clamps in :func:`row_shard_count` / :func:`grid_shard_counts` can pick
     fewer shards than visible devices so tiny batches are not mostly
     padding); ``None`` keeps jax.make_mesh's all-devices default.
     """
-    kwargs = axis_types_kwargs(len(shape))
-    if devices is not None:
-        kwargs["devices"] = devices
-    try:
-        return jax.make_mesh(shape, axis_names, **kwargs)
-    except TypeError:  # pragma: no cover - pre-`devices=` JAX
-        if devices is None:
-            raise
-        import numpy as np
-        return Mesh(np.asarray(devices).reshape(shape), axis_names)
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def shard_map(worker, mesh, in_specs, out_specs):
-    """Version shim over ``shard_map``'s migration into the jax namespace.
+    """``jax.shard_map`` with replication checking off (callers use
+    collectives the checker cannot type)."""
+    return jax.shard_map(worker, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
-    New JAX: ``jax.shard_map(..., check_vma=...)``; old JAX:
-    ``jax.experimental.shard_map.shard_map(..., check_rep=...)``.  Replication
-    checking is disabled (callers use collectives the checker cannot type).
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn(worker, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as exp_shard_map
-    return exp_shard_map(worker, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
 
 def row_shard_count(n_rows: int) -> int:
     """How many ways a leading batch axis of ``n_rows`` should shard.

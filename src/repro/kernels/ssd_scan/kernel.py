@@ -21,50 +21,61 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_scr,
-                *, chunk: int):
+def _ssd_kernel(x_ref, dtc_ref, dtr_ref, a_ref, b_ref, c_ref, o_ref,
+                state_scr, *, chunk: int):
+    g = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
+    hi = jax.lax.Precision.HIGHEST
     x = x_ref[0].astype(jnp.float32)          # (cl, P)
-    dt = dt_ref[0].astype(jnp.float32)        # (1, cl) -> (cl,)
-    dt = dt.reshape(chunk)
-    a = a_ref[0, 0]                           # scalar A_h (negative)
+    dt_c = dtc_ref[0].astype(jnp.float32)     # (cl, 1)
+    dt_r = dtr_ref[0].astype(jnp.float32)     # (1, cl)
+    a = a_ref[g]                              # scalar A_h (negative)
     bm = b_ref[0].astype(jnp.float32)         # (cl, N)
     cm = c_ref[0].astype(jnp.float32)         # (cl, N)
 
-    dA = dt * a                               # (cl,)
-    cs = jnp.cumsum(dA)                       # inclusive
-    xdt = x * dt[:, None]
+    # Inclusive cumsum of dA as a lower-triangular matmul (Mosaic has no
+    # cumsum), in both the column and the row layout the decays need.
+    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = jj <= ii
+    tri = causal.astype(jnp.float32)
+    cs_c = jax.lax.dot_general(
+        tri, dt_c * a, (((1,), (0,)), ((), ())), precision=hi,
+        preferred_element_type=jnp.float32)   # (cl, 1)
+    cs_r = jax.lax.dot_general(
+        dt_r * a, tri, (((1,), (1,)), ((), ())), precision=hi,
+        preferred_element_type=jnp.float32)   # (1, cl)
+    cs_last = cs_c[chunk - 1:, :]             # (1, 1)
+    xdt = x * dt_c
 
     # Intra-chunk: M[i, j] = (C_i . B_j) * exp(cs_i - cs_j) for j <= i
     G = jax.lax.dot_general(
         cm, bm, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)   # (cl, cl)
-    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.exp(cs[:, None] - cs[None, :])
-    M = jnp.where(jj <= ii, G * decay, 0.0)
+    M = jnp.where(causal, G * jnp.exp(cs_c - cs_r), 0.0)
     y = jax.lax.dot_general(
         M, xdt, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)   # (cl, P)
 
     # Inter-chunk: carried state contribution + state update
     state = state_scr[...]                    # (P, N)
-    sdec = jnp.exp(cs)                        # (cl,)
     y_inter = jax.lax.dot_general(
         cm, state, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)   # (cl, P)
-    y = y + y_inter * sdec[:, None]
+    y = y + y_inter * jnp.exp(cs_c)
 
-    edec = jnp.exp(cs[-1] - cs)               # decay j..chunk end
+    edec = jnp.exp(cs_last - cs_c)            # (cl, 1) decay j..chunk end
     contrib = jax.lax.dot_general(
-        xdt, bm * edec[:, None], (((0,), (0,)), ((), ())),
+        xdt, bm * edec, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)   # (P, N)
-    state_scr[...] = jnp.exp(cs[-1]) * state + contrib
+    # (1, 1) -> (1, N) -> (P, N): Mosaic broadcasts one axis at a time.
+    sdec = jnp.exp(jnp.broadcast_to(cs_last, (1, state.shape[1])))
+    state_scr[...] = sdec * state + contrib
 
     o_ref[0] = y.astype(o_ref.dtype)
 
@@ -79,10 +90,14 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
     assert s % chunk == 0, (s, chunk)
     nc = s // chunk
     bh = b * h
-    # (B*H, S, P); dt -> (B*H, S); B/C shared across heads: (B, S, N)
+    # (B*H, S, P); dt as (B*H, S) columns and rows; B/C shared across
+    # heads: (B, S, N)
     xr = x.transpose(0, 2, 1, 3).reshape(bh, s, p)
     dtr = dt.transpose(0, 2, 1).reshape(bh, 1, s)
-    ar = jnp.broadcast_to(A[None, :], (b, h)).reshape(bh, 1)
+    dtc = dtr.reshape(bh, s, 1)
+    # Per-row A_h, whole array in SMEM: a (1,) block of a (B*H,) array
+    # breaks the TPU's (8, 128) tiling rule, a scalar read from SMEM does not.
+    ar = jnp.broadcast_to(A[None, :], (b, h)).reshape(bh).astype(jnp.float32)
 
     grid = (bh, nc)
     out = pl.pallas_call(
@@ -90,8 +105,9 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, chunk, p), lambda g, j: (g, j, 0)),
+            pl.BlockSpec((1, chunk, 1), lambda g, j: (g, j, 0)),
             pl.BlockSpec((1, 1, chunk), lambda g, j: (g, 0, j)),
-            pl.BlockSpec((1, 1), lambda g, j: (g, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             # B/C are head-shared: index the batch row b = g // h.
             pl.BlockSpec((1, chunk, n), lambda g, j: (g // h, j, 0)),
             pl.BlockSpec((1, chunk, n), lambda g, j: (g // h, j, 0)),
@@ -100,5 +116,5 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((bh, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(xr, dtr, ar, Bm, Cm)
+    )(xr, dtc, dtr, ar, Bm, Cm)
     return out.reshape(b, h, s, p).transpose(0, 2, 1, 3)

@@ -42,6 +42,7 @@ from repro.core.coordinator import IntervalRecord, fig8_schedule
 from repro.core.dispatch import record_dispatch
 from repro.core.prefetch_controller import throttle_decision_jax
 from repro.core.types import CBPParams, Mode, PrefetchMode
+from repro.core.x64 import x64_context
 
 #: Segment kind codes — shared with the simulator's fused timeline so the
 #: two fused subsystems cannot drift on schedule encoding.
@@ -254,8 +255,6 @@ def run_fused_schedule(
     well-formedness via ``CBPParams``) are hoisted out of the traced
     region, exactly like the simulator's fused path.
     """
-    from repro.core.cache_controller_jax import _x64_context
-
     import jax.numpy as jnp
 
     params = params or CBPParams()
@@ -282,7 +281,7 @@ def run_fused_schedule(
         prefetch_mode == PrefetchMode.DYNAMIC,
         allocator_backend)
     record_dispatch()
-    with _x64_context():
+    with x64_context():
         scalars = (jnp.asarray(params.min_ways, dtype=jnp.int64),
                    jnp.asarray(total_bandwidth, dtype=jnp.float64),
                    jnp.asarray(params.min_bandwidth_allocation,

@@ -87,7 +87,7 @@ class ServingEngine:
                               float(self.cfg.batch_slots))
         self.pool = PagedKVPool(self.cfg.total_pages, n_streams)
         self.kv = model.init_cache(self.cfg.batch_slots, self.cfg.max_len,
-                                   dtype=jnp.float32)
+                                   dtype=jnp.dtype(model.cfg.kv_cache_dtype))
         self._decode = jax.jit(model.decode_step)
         # CBP state
         self.slot_share = np.full(n_streams,

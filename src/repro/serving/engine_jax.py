@@ -216,9 +216,12 @@ class JitServingEngine:
             "tokens_done": np.zeros((G, npg), dtype=np.int32),
             "last_rates": np.zeros((G, npg), dtype=np.float32),
             "reconfigs": np.zeros((G,), dtype=np.int32),
+            "nonfinite_logits": np.zeros((G,), dtype=np.int32),
         }
         self._prime(q)
-        kv = self.model.init_cache(G * spg, cfgE.max_len, dtype=jnp.float32)
+        kv = self.model.init_cache(
+            G * spg, cfgE.max_len,
+            dtype=jnp.dtype(self.model.cfg.kv_cache_dtype))
         S = G * spg
         for leaf in jax.tree.leaves(kv):
             if leaf.ndim < 2 or leaf.shape[1] != S:
@@ -279,6 +282,7 @@ class JitServingEngine:
             q["pos"].reshape(G * spg))
         nxt = jnp.argmax(logits[:, -1, :], axis=-1)
         nxt = nxt.astype(jnp.int32).reshape(G, spg)
+        bad = ~jnp.isfinite(logits[:, -1, :]).all(-1).reshape(G, spg) & upd
 
         # ---- coarse paged-KV accounting at the current position ---------
         strm = q["slot_stream"]
@@ -403,7 +407,8 @@ class JitServingEngine:
             prefetch_hits=prefetch_hits, prefetch_misses=prefetch_misses,
             occupancy=occupancy, evictions=evictions,
             stream_active=stream_active, queue_wait=queue_wait,
-            tokens_done=tokens_done)
+            tokens_done=tokens_done,
+            nonfinite_logits=q["nonfinite_logits"] + bad.sum(-1))
         return {"kv": kv, "q": q2}
 
     def _reconfigure(self, st: Dict, did_full) -> Dict:
@@ -569,6 +574,7 @@ class JitServingEngine:
         def flat(name):
             return q[name].reshape(-1)  # stream s = g * npg + s_local
 
+        self.nonfinite_logits = int(q["nonfinite_logits"].sum())
         self.steps = int(q["steps"].max())
         self.reconfigs = int(q["reconfigs"].max())
         self.slot_share = flat("slot_share").astype(np.float64)

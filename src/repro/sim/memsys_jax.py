@@ -10,13 +10,12 @@ Table-3 sweep runner (:mod:`repro.sim.sweep`) builds on.
 Contract: for identical inputs, :func:`evaluate` / :func:`utility_curves`
 here must match ``memsys.evaluate`` / ``memsys.utility_curves`` to within
 1e-5 relative tolerance (enforced by ``tests/test_sim_sweep.py``).  The
-solve runs in float64 (via the ``enable_x64`` context) so the parity gap is
-dominated by op-ordering, not precision.  The numpy implementation stays
+solve runs in float64 (:func:`repro.core.x64.x64_context`) so the parity
+gap is dominated by op-ordering, not precision.  The numpy implementation stays
 the golden reference — change that first, then mirror here.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 from typing import Dict, Union
 
@@ -25,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.dispatch import record_dispatch
+from repro.core.x64 import x64_context
 from repro.sim.apps import MODEL_FIELDS, AppArrays
 from repro.sim.memsys import (
     BANK_SKEW,
@@ -40,22 +40,10 @@ from repro.sim.memsys import (
     SteadyState,
 )
 
-try:  # pragma: no cover - present on every supported JAX
-    from jax.experimental import enable_x64 as _enable_x64
-except ImportError:  # pragma: no cover
-    _enable_x64 = None
-
 #: AppArrays fields the model consumes (single source: apps.MODEL_FIELDS).
 PARAM_FIELDS = MODEL_FIELDS
 
 Params = Dict[str, jnp.ndarray]
-
-
-def x64_context():
-    """Run the solve in float64 to honour the parity contract."""
-    if _enable_x64 is None:
-        return contextlib.nullcontext()
-    return _enable_x64()
 
 
 def app_params(apps: Union[AppArrays, Params]) -> Params:

@@ -663,13 +663,13 @@ def _search_jax_family(
 ) -> Tuple[np.ndarray, np.ndarray, Optional[np.ndarray]]:
     """One device program: chunked grid scan + top-k for one family."""
     from repro.core.dispatch import record_dispatch
-    from repro.sim import memsys_jax
+    from repro.core.x64 import x64_context
 
     w_pad = sharded["baseline_ipc"].shape[0]
     replicated = _family_tables(grid, w_pad, k, chunk_elements)
     fn = _compiled_search(k, iters, n_shards, banks, multi)
     record_dispatch()
-    with memsys_jax.x64_context():
+    with x64_context():
         out = fn(sharded, replicated)
         top_ws = np.asarray(out["topk_ws"])[:w]
         top_idx = np.asarray(out["topk_index"])[:w].astype(np.int64)
@@ -690,7 +690,7 @@ def _search_jax_stacked(
 ):
     """ONE device program scanning every family's grid back to back."""
     from repro.core.dispatch import record_dispatch
-    from repro.sim import memsys_jax
+    from repro.core.x64 import x64_context
 
     w_pad = sharded["baseline_ipc"].shape[0]
     names = list(grids)
@@ -704,7 +704,7 @@ def _search_jax_stacked(
     topk_ws: Dict[str, np.ndarray] = {}
     topk_idx: Dict[str, np.ndarray] = {}
     topk_f: Dict[str, np.ndarray] = {}
-    with memsys_jax.x64_context():
+    with x64_context():
         out = fn(sharded, replicated)
         for fi, name in enumerate(names):
             topk_ws[name] = np.asarray(out[f"topk_ws{fi}"])[:w]

@@ -73,6 +73,7 @@ import numpy as np
 
 from repro.core import CBPParams
 from repro.core.dispatch import record_dispatch
+from repro.core.x64 import x64_context
 from repro.runtime.fault import StragglerWatchdog
 from repro.runtime.faultinject import FaultPlan
 from repro.sim import memsys_jax, timeline_jax
@@ -481,7 +482,7 @@ class _StreamRunner:
         import jax
         import jax.numpy as jnp
 
-        with memsys_jax.x64_context():
+        with x64_context():
             ipc_stack = jnp.stack(
                 [d["ipc_acc"] for d in pending.device_results])
             if self.plan.poisons(chunk_idx):

@@ -85,6 +85,7 @@ from repro.core.cache_controller_jax import lookahead_masked_traced
 from repro.core.coordinator import ScheduleSegment
 from repro.core.dispatch import record_dispatch
 from repro.core.prefetch_controller import throttle_decision_jax
+from repro.core.x64 import x64_context
 from repro.sim import memsys_jax, policies
 from repro.sim.apps import AppArrays
 from repro.sim.memsys import FIXED_POINT_ITERS, FREQ_GHZ
@@ -978,7 +979,7 @@ def run_timelines_async(
         max(s.bandwidth_banks for s in specs))
     record_dispatch()
     donated = None
-    with memsys_jax.x64_context():
+    with x64_context():
         if donate:
             # Stable device identities for the donated carry buffers:
             # transfer first, keep the handles, and hand exactly those
@@ -1042,7 +1043,7 @@ def _dispatch_buckets(buckets, tables, accum, grid, flags, replicated,
                            donate)
     record_dispatch()
     donated = None
-    with memsys_jax.x64_context():
+    with x64_context():
         if donate:
             # See run_timelines_async: transfer the carry leaves, keep
             # the handles, donate exactly those.

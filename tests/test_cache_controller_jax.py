@@ -9,7 +9,8 @@ these tests assert exact equality.
 """
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (
     CacheController,
@@ -18,6 +19,7 @@ from repro.core import (
     lookahead_allocate,
 )
 from repro.core import cache_controller_jax as ccj
+from repro.core.x64 import x64_context
 
 
 def _concave_curves(rng, n, total):
@@ -147,6 +149,20 @@ def test_masked_all_inactive_distributes_remainder():
     np.testing.assert_array_equal(ref, [8, 8, 7, 7])
 
 
+def test_lookahead_runs_with_x64_off_globally():
+    """The batched allocator enters its own float64 scope: it needs no
+    process-wide ``jax_enable_x64`` and matches the golden without it."""
+    import jax
+
+    assert not jax.config.jax_enable_x64
+    rng = np.random.default_rng(17)
+    curves = np.stack([_concave_curves(rng, 6, 48) for _ in range(3)])
+    out = ccj.lookahead_allocate(curves, 48, min_units=2)
+    ref = np.stack([lookahead_allocate(c, 48, min_units=2) for c in curves])
+    np.testing.assert_array_equal(out, ref)
+    assert not jax.config.jax_enable_x64
+
+
 def test_cache_controller_backend_dispatch():
     """Both backends agree through the CacheController facade, and only
     the numpy backend touches the host allocator counter."""
@@ -214,7 +230,7 @@ def test_greedy_loop_trip_bound_never_abandons_live_rows():
         _concave_curves(np.random.default_rng(1), n, U),
     ])
     mins = np.array([0, 3, 2, 1])
-    with ccj._x64_context():
+    with x64_context():
         alloc, balance, stuck, it = map(np.asarray, ccj._greedy_loop(
             jnp.asarray(curves, jnp.float64), jnp.asarray(mins),
             jnp.ones((4, n), dtype=bool),

@@ -10,7 +10,8 @@ branch.
 """
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (
     allocate_bandwidth,
@@ -19,7 +20,7 @@ from repro.core import (
     throttle_decision,
     throttle_decision_jax,
 )
-from repro.sim.memsys_jax import x64_context
+from repro.core.x64 import x64_context
 
 
 def _bw_jax(delay, total, min_alloc):

@@ -1,7 +1,8 @@
 """Unit + property tests for the CBP controllers (paper §3.2)."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (
     SampledATD,
@@ -146,7 +147,7 @@ def test_throttle_threshold():
     thr=st.floats(1.0, 1.5),
 )
 def test_throttle_property(ipc, speedup, thr):
-    from _hypothesis_compat import assume
+    from hypothesis import assume
     assume(abs(speedup - thr) > 1e-6)  # avoid the float knife-edge
     on = throttle_decision(
         np.array([ipc * speedup]), np.array([ipc]), speedup_threshold=thr)

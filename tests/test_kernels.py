@@ -6,7 +6,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels.cbp_matmul.kernel import cbp_matmul, vmem_footprint_bytes
 from repro.kernels.cbp_matmul.ref import matmul_ref

@@ -11,7 +11,8 @@ measure-zero, so these tests assert exact equality.
 """
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import CacheController, allocator_calls
 from repro.core import cache_controller as cc

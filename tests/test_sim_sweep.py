@@ -56,6 +56,22 @@ def test_memsys_jax_matches_numpy_reference(cache_partitioned,
             assert err < 1e-5, (field, err)
 
 
+def test_interval_model_runs_in_float64_with_x64_off_globally():
+    """The x64 scope is real: with ``jax_enable_x64`` off process-wide, the
+    interval model still solves and returns float64 (a silent float32
+    fallback is what broke the 1e-5 parity contract once)."""
+    import jax
+
+    assert not jax.config.jax_enable_x64
+    apps = stack(WORKLOADS["w1"][:4])
+    out = memsys_jax.evaluate(apps, np.full(4, 16.0), np.full(4, 4.0),
+                              np.zeros(4), total_cache_units=64.0,
+                              total_bandwidth_gbps=16.0)
+    for field in FIELDS:
+        assert getattr(out, field).dtype == np.float64, field
+    assert not jax.config.jax_enable_x64
+
+
 def test_utility_curves_jax_matches_numpy_reference():
     rng = np.random.default_rng(7)
     apps = stack(WORKLOADS["w3"])

@@ -45,7 +45,8 @@ from repro.sim.static_search import (
     search_static,
 )
 from repro.sim.workloads import random_workloads
-from tests._hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 # --------------------------------------------------------------------- #
 # parity
@@ -182,7 +183,7 @@ def test_backend_dispatch_validates():
 
 
 # --------------------------------------------------------------------- #
-# properties (hypothesis via tests/_hypothesis_compat.py)
+# properties (hypothesis)
 # --------------------------------------------------------------------- #
 
 
@@ -293,8 +294,29 @@ def test_arbitrary_napp_workloads_and_custom_grids():
                         options=opts, k=2, backend="numpy")
     np.testing.assert_allclose(res.topk_ws["all3"], ref.topk_ws["all3"],
                                rtol=1e-5)
-    np.testing.assert_array_equal(res.topk_index["all3"],
-                                  ref.topk_index["all3"])
+    # Workload 1 draws zeusmp twice: configs that swap its two allocations
+    # tie in exact arithmetic, and float64 op ordering may rank such a pair
+    # either way.  Where an index differs, the golden must score the
+    # device's pick equal to its own (to 1e-12), i.e. an exact tie.
+    from repro.sim import memsys
+    from repro.sim.apps import stack
+    from repro.sim.static_search import FIG5_ITERS
+
+    grid = ref.grids["all3"]
+    for wi in range(len(wls)):
+        got, want = res.topk_index["all3"][wi], ref.topk_index["all3"][wi]
+        diff = got != want
+        if not diff.any():
+            continue
+        idx = np.concatenate([got[diff], want[diff]])
+        ss = memsys.evaluate(
+            stack(wls[wi]), grid.cache[idx], grid.bandwidth[idx],
+            grid.prefetch[idx], total_cache_units=grid.total_cache_units,
+            total_bandwidth_gbps=grid.total_bandwidth_gbps,
+            iters=FIG5_ITERS)
+        ws = np.mean(ss.ipc / ref.baseline_ipc[wi], axis=-1)
+        np.testing.assert_allclose(ws[: diff.sum()], ws[diff.sum():],
+                                   rtol=1e-12)
     cfg = res.best_config("all3")
     assert cfg["cache_units"].shape == (2, 5)
     assert (cfg["cache_units"].sum(axis=-1) <= 16.0 * 5 + 1e-9).all()

@@ -7,7 +7,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.checkpoint import CheckpointManager, load_pytree, save_pytree
 from repro.data import PrefetchPipeline, SyntheticTokens
