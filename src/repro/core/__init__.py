@@ -29,6 +29,7 @@ from repro.core.coordinator import (
 )
 from repro.core.dispatch import (
     device_dispatches,
+    greedy_trips,
     record_dispatch,
     reset_device_dispatches,
 )
@@ -63,6 +64,7 @@ __all__ = [
     "ScheduleSegment",
     "fig8_schedule",
     "device_dispatches",
+    "greedy_trips",
     "record_dispatch",
     "reset_device_dispatches",
     "PrefetchController",

@@ -78,7 +78,7 @@ def _greedy_loop(
 
     Returns ``(alloc, balance, stuck, it)`` — the greedy allocation, the
     undistributed balance for :func:`_zero_spread`, the per-row stuck
-    flags, and the body-application count (two per while trip), which the
+    flags, and the body-application count (four per while trip), which the
     trip-bound regression test audits.
     """
     B, n, _ = curves.shape
@@ -195,25 +195,30 @@ def _zero_spread(curves, alloc, balance, active, remaining):
 
 
 def _greedy_core(curves, min_units, active, remaining, total_units: int,
-                 backend=None):
+                 backend=None, with_trips: bool = False):
     """Backend-dispatched greedy + shared spread.
 
     ``backend="jax"`` runs the batched incremental-refresh while_loop;
     ``backend="pallas"`` runs the per-row VMEM-resident kernel
     (:mod:`repro.kernels.lookahead_greedy`).  Both feed the same
     :func:`_zero_spread`, so they are interchangeable bit for bit.
+    ``with_trips=True`` returns ``(alloc, trips)``: the while_loop's
+    body-application count (int32), or ``None`` from the kernel, which
+    has no such loop.
     """
     backend = _resolve_backend(backend)
+    trips = None
     if backend == "pallas":
         from repro.kernels.lookahead_greedy import ops as _lookahead_ops
         alloc, balance = _lookahead_ops.lookahead_greedy(
             curves, min_units, active.astype(jnp.int32),
             remaining, total_units=total_units)
     else:
-        alloc, balance, _stuck, _it = _greedy_loop(
+        alloc, balance, _stuck, trips = _greedy_loop(
             curves, min_units, active, remaining,
             total_units=total_units)
-    return _zero_spread(curves, alloc, balance, active, remaining)
+    out = _zero_spread(curves, alloc, balance, active, remaining)
+    return (out, trips) if with_trips else out
 
 
 def lookahead_traced(curves, min_units, total_units: int, backend=None):
@@ -239,20 +244,22 @@ def lookahead_masked_traced(curves, min_units, active, total_units: int,
     Pins inactive clients at the floor and runs the greedy over the active
     subset; the all-inactive fallback (even split, remainder to the lowest
     indices) is folded in as a ``where`` so the whole decision stays on
-    device.
+    device.  Returns ``(alloc, trips)``: the greedy's body applications as
+    :func:`_greedy_core` counts them.
     """
     B, n, _ = curves.shape
     min32 = min_units.astype(jnp.int32)
     remaining = (total_units
                  - min32 * (n - active.sum(axis=-1).astype(jnp.int32)))
-    out = _greedy_core(curves, min_units, active, remaining,
-                       total_units=total_units, backend=backend)
+    out, trips = _greedy_core(curves, min_units, active, remaining,
+                              total_units=total_units, backend=backend,
+                              with_trips=True)
     none_active = ~active.any(axis=-1)
     extra = total_units - n * min32
     even = (min32[:, None] + extra[:, None] // n
             + (jnp.arange(n, dtype=jnp.int32)[None, :]
                < (extra % n)[:, None]))
-    return jnp.where(none_active[:, None], even, out)
+    return jnp.where(none_active[:, None], even, out), trips
 
 
 def _validate(curves: np.ndarray, total_units: int,

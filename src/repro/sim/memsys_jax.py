@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.dispatch import record_dispatch
+from repro.core.dispatch import INTERVAL_SCOPE, record_dispatch
 from repro.core.x64 import x64_context
 from repro.sim.apps import MODEL_FIELDS, AppArrays
 from repro.sim.memsys import (
@@ -101,6 +101,7 @@ def _banked_queueing(traffic_q, bw, banks, max_banks: int):
     jax.jit,
     static_argnames=("cache_partitioned", "bandwidth_partitioned", "iters",
                      "bandwidth_banks"))
+@jax.named_scope(INTERVAL_SCOPE)
 def _evaluate_jit(
     params: Params,
     cache_units: jnp.ndarray,
@@ -186,6 +187,7 @@ def _evaluate_jit(
     return jax.lax.fori_loop(0, iters, body, init)
 
 
+@jax.named_scope(INTERVAL_SCOPE)
 def _evaluate_rowflags(
     params: Params,
     cache_units: jnp.ndarray,

@@ -12,21 +12,24 @@ Since PR 2 the Lookahead cache allocator is batched too
 (:mod:`repro.core.cache_controller_jax`): every reconfiguration boundary is
 one jitted device call over all mixes, so a full sweep performs **zero**
 per-mix host allocator calls (assert with
-:func:`repro.core.allocator_calls`) and host transfers drop to one per
-Fig. 8 segment.  CPpf's friendly-mask allocation is vectorized the same
-way (`CacheController.allocate_masked`).
+:func:`repro.core.allocator_calls`).  CPpf's friendly-mask allocation is
+vectorized the same way (`CacheController.allocate_masked`).
 
 Since PR 3 the whole Fig. 8 timeline of each manager is ONE jitted device
 program (:mod:`repro.sim.timeline_jax`): the bandwidth controller and the
 prefetch throttle run inside the scan next to the batched Lookahead
-allocator, so a full sweep performs zero per-segment host transfers.
+allocator, so no segment returns to the host.
 Since PR 5 the *manager axis* is batched too: every Table-3 manager's
 segment table and knob flags stack along a leading axis inside one
 program (:func:`repro.sim.timeline_jax.run_timelines`), so a full sweep
-is AT MOST TWO device dispatches — the stacked manager set plus the
-shared baseline evaluation (counter:
-:func:`repro.core.device_dispatches`) — and the 2-D (manager, mix) grid
-shards across devices via :func:`repro.distributed.shard_grid`.  The
+calls TWO jitted entries — the stacked manager set plus the shared
+baseline evaluation (counter: :func:`repro.core.device_dispatches`) — and
+the 2-D (manager, mix) grid shards across devices via
+:func:`repro.distributed.shard_grid`.  The counter counts those entries
+only: collecting the results slices each manager's fields out of the
+stacked outputs with small eager programs and fetches each field with a
+transfer of its own, which a profiler trace shows inside the sweep's
+``cbp.sweep.collect`` span (:func:`_run_sweep_one`).  The
 PR 3/4 one-program-per-manager path survives as
 ``CMPConfig(timeline_backend="fused")`` (the stacking parity reference —
 bit-identical per-(manager, mix) results), the PR 2 per-segment host
@@ -72,6 +75,7 @@ from repro.core import (
     fig8_schedule,
     throttle_decision,
 )
+from repro.core.dispatch import span
 from repro.core.types import IntervalStats
 from repro.sim import memsys, memsys_jax, policies, timeline_jax
 from repro.sim.apps import AppArrays, stack_mixes
@@ -591,6 +595,32 @@ def _manager_spec(plant: BatchedCMPPlant, name: str, total_ms: float,
     return spec
 
 
+def _stage_managers(
+    plant: BatchedCMPPlant,
+    names: Sequence[str],
+    total_ms: float,
+    params: CBPParams,
+    params_rows: Optional[Sequence[CBPParams]] = None,
+) -> Tuple[List[timeline_jax.TimelineSpec], timeline_jax.StagedTimelines]:
+    """The manager set's specs and its stacked program, staged on the host
+    (:func:`repro.sim.timeline_jax.stage_timelines`), not yet dispatched."""
+    rows = _per_row_params(params, params_rows, plant.n_mixes)
+    specs = [_manager_spec(plant, name, total_ms, rows.schedule)
+             for name in names]
+    staged = timeline_jax.stage_timelines(
+        plant.apps, specs,
+        total_units=plant.total_cache_units,
+        total_bandwidth=plant.total_bandwidth,
+        llc_extra_cycles=plant.config.llc_extra_cycles,
+        min_ways=rows.min_ways,
+        speedup_threshold=rows.speedup_threshold,
+        min_bandwidth_allocation=rows.min_bandwidth_allocation,
+        atd_decay=rows.atd_decay,
+        bandwidth_delay_decay=rows.bandwidth_delay_decay,
+    )
+    return specs, staged
+
+
 def _run_managers_stacked(
     plant: BatchedCMPPlant,
     names: Sequence[str],
@@ -603,23 +633,20 @@ def _run_managers_stacked(
     Each manager keeps its own segment table and knob flags; the tables
     stack along the leading manager axis and the (manager, mix) grid
     shards over devices (:func:`repro.sim.timeline_jax.run_timelines`).
-    Capacity invariants are checked per manager exactly as on the
-    per-manager paths.
     """
-    rows = _per_row_params(params, params_rows, plant.n_mixes)
-    specs = [_manager_spec(plant, name, total_ms, rows.schedule)
-             for name in names]
-    results = timeline_jax.run_timelines(
-        plant.apps, specs,
-        total_units=plant.total_cache_units,
-        total_bandwidth=plant.total_bandwidth,
-        llc_extra_cycles=plant.config.llc_extra_cycles,
-        min_ways=rows.min_ways,
-        speedup_threshold=rows.speedup_threshold,
-        min_bandwidth_allocation=rows.min_bandwidth_allocation,
-        atd_decay=rows.atd_decay,
-        bandwidth_delay_decay=rows.bandwidth_delay_decay,
-    )
+    specs, staged = _stage_managers(plant, names, total_ms, params,
+                                    params_rows)
+    return _collect_managers(plant, specs, staged.dispatch().result())
+
+
+def _collect_managers(
+    plant: BatchedCMPPlant,
+    specs: Sequence[timeline_jax.TimelineSpec],
+    results: Sequence[timeline_jax.TimelineResult],
+) -> Dict[str, Tuple[np.ndarray, Allocation]]:
+    """Per manager ``(mean IPC, final allocation)`` from the fetched
+    timelines.  Capacity invariants are checked per manager exactly as on
+    the per-manager paths."""
     out: Dict[str, Tuple[np.ndarray, Allocation]] = {}
     for spec, res in zip(specs, results):
         if spec.variant == "cppf":
@@ -722,6 +749,45 @@ class SweepResult:
         return out
 
 
+def _run_sweep_one(
+    mixes: Sequence[Sequence[str]],
+    managers: Optional[Sequence[str]],
+    total_ms: float,
+    params: CBPParams,
+    config: Optional[CMPConfig],
+) -> SweepResult:
+    """:func:`run_sweep` with one ``CBPParams``, in host spans
+    (:func:`repro.core.dispatch.span`) that a profiler trace shows beside
+    the device's ops: ``cbp.sweep.prepare`` (plant, specs, segment tables,
+    the stacked grid), then the stacked program's dispatch, then
+    ``cbp.sweep.collect`` (the per-spec slices, the fetch, the capacity
+    checks and the mean IPC) and ``cbp.sweep.baseline`` (the baseline
+    program and its fetch).  Timeline backends other than "stacked" run
+    their managers between the prepare and baseline spans, in no span."""
+    with span("cbp.sweep.prepare"):
+        plant = BatchedCMPPlant(mixes, config)
+        names = list(MANAGER_NAMES) if managers is None else list(managers)
+        policies.validate_manager_names(names)
+        stacked = plant.timeline_backend == "stacked" and bool(names)
+        if stacked:
+            specs, staged = _stage_managers(plant, names, total_ms, params)
+    if stacked:
+        pending = staged.dispatch()
+        with span("cbp.sweep.collect"):
+            out = _collect_managers(plant, specs, pending.result())
+    else:
+        out = _run_managers(plant, names, total_ms, params)
+    with span("cbp.sweep.baseline"):
+        baseline = baseline_ipc_batched(plant)
+    return SweepResult(
+        manager_names=names,
+        mixes=plant.mixes,
+        ipc={name: mipc for name, (mipc, _) in out.items()},
+        final_alloc={name: alloc for name, (_, alloc) in out.items()},
+        baseline_ipc=baseline,
+    )
+
+
 def run_sweep(
     mixes: Sequence[Sequence[str]],
     managers: Optional[Sequence[str]] = None,
@@ -744,25 +810,12 @@ def run_sweep(
         params run as separate batches of the same sweep.  Mutually
         exclusive with ``params``.
     """
+    if param_grid is None:
+        return _run_sweep_one(mixes, managers, total_ms,
+                              params or CBPParams(), config)
     plant = BatchedCMPPlant(mixes, config)
     names = list(MANAGER_NAMES) if managers is None else list(managers)
     policies.validate_manager_names(names)   # UnknownManagerError on a typo
-
-    if param_grid is None:
-        params = params or CBPParams()
-        ipc: Dict[str, np.ndarray] = {}
-        final: Dict[str, Allocation] = {}
-        for name, (mipc, alloc) in _run_managers(
-                plant, names, total_ms, params).items():
-            ipc[name], final[name] = mipc, alloc
-        return SweepResult(
-            manager_names=names,
-            mixes=plant.mixes,
-            ipc=ipc,
-            final_alloc=final,
-            baseline_ipc=baseline_ipc_batched(plant),
-        )
-
     if params is not None:
         raise ValueError("pass either params or param_grid, not both")
     grid = list(param_grid)

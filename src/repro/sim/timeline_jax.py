@@ -8,9 +8,14 @@ timelines pad with frozen ``NOOP`` rows), and the per-manager knob flags —
 ``cache_dynamic``, ``bandwidth_dynamic``, ``cache_partitioned``,
 ``bandwidth_partitioned``, the CPpf variant mask — become traced ``(K,)``
 arrays instead of static trace constants.  A full Table-3 sweep is then
-**one device program total** (plus the shared baseline evaluation): inputs
-transfer once, results transfer once, zero per-manager or per-segment host
-round-trips (counter: :func:`repro.core.device_dispatches`).
+**one stacked program** (plus the shared baseline evaluation; counter:
+:func:`repro.core.device_dispatches`), with no per-manager or per-segment
+program.  Collecting its outputs is not one transfer: each spec's fields
+are sliced out with small eager programs and fetched one by one
+(:class:`PendingTimelines`), outside that counter.  The program also
+returns the boundary greedy's body-application count, which
+:meth:`PendingTimelines.result` adds to
+:func:`repro.core.dispatch.greedy_trips`.
 
 Stacking is exact, not approximate
     Batch rows never interact — the model, the batched Lookahead greedy,
@@ -46,7 +51,11 @@ Controllers in the traced region
     n > total`` feasibility checks hoisted out of the traced region.
     The interval model runs through
     :func:`repro.sim.memsys_jax._evaluate_rowflags` so each manager row
-    gets its own partitioned/unpartitioned regime.
+    gets its own partitioned/unpartitioned regime.  The model runs under
+    the named scope ``cbp.interval`` and the boundary greedy (Lookahead
+    and the registry's branches) under ``cbp.greedy``
+    (:mod:`repro.core.dispatch`), so a profiler trace can split the scan's
+    device time between them.
 
 Sharding
     The (manager, mix) grid is sharded across devices with
@@ -70,7 +79,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import List, Optional, Sequence, Tuple, Union
+from typing import Any, Callable, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -83,7 +92,11 @@ from repro.core.bandwidth_controller import (
 )
 from repro.core.cache_controller_jax import lookahead_masked_traced
 from repro.core.coordinator import ScheduleSegment
-from repro.core.dispatch import record_dispatch
+from repro.core.dispatch import (
+    GREEDY_SCOPE,
+    record_dispatch,
+    record_greedy_trips,
+)
 from repro.core.prefetch_controller import throttle_decision_jax
 from repro.core.x64 import x64_context
 from repro.sim import memsys_jax, policies
@@ -105,6 +118,11 @@ _KIND_CODES = {"sample_off": SAMPLE_OFF, "sample_on": SAMPLE_ON, "run": RUN}
 #: precisely these buffers to XLA for in-place reuse — every donation is
 #: consumed, none wasted (no "unusable donation" lowering warnings).
 _CARRY_KEYS = ("units0", "bw0", "pf0", "active0")
+
+#: The worker's extra output: the boundary greedy's body applications over
+#: the whole scan, shaped ``(1, 1)`` per shard so that it shards like the
+#: grid; never sliced per spec (:meth:`PendingTimelines.result` sums it).
+_TRIPS_KEY = "greedy_trips"
 
 
 def segment_table(
@@ -401,6 +419,7 @@ def _make_worker(
             else:
                 units, bw, w_off, w_on, bw_acc, active, do_r, realloc_k \
                     = operand
+            trips = jnp.int32(0)
             if any_bandwidth_dynamic:
                 # Algorithm-1 bandwidth update first: it reads none of the
                 # cache state, and running it before the cache gather lets
@@ -439,8 +458,9 @@ def _make_worker(
                      for g in range(G)], axis=0)
                 min_all = jnp.concatenate(
                     [blk(min32, offs[g]) for g in range(G)], axis=0)
-                fresh = lookahead_masked_traced(
-                    atd_all, min_all, act_all, total_units)
+                with jax.named_scope(GREEDY_SCOPE):
+                    fresh, trips = lookahead_masked_traced(
+                        atd_all, min_all, act_all, total_units)
                 if any_policy:
                     # Registry dispatch: each reconfiguring manager's block
                     # goes through its family's boundary branch.  Branch 0
@@ -491,8 +511,9 @@ def _make_worker(
                                 blk(slow, offs[g]),
                                 blk(qos_bound, offs[g]),
                                 blk(qos_gain, offs[g]))
-                        units_b, bw_new_b = jax.lax.switch(
-                            cache_pol_k[order[g]], branches, op_g)
+                        with jax.named_scope(GREEDY_SCOPE):
+                            units_b, bw_new_b = jax.lax.switch(
+                                cache_pol_k[order[g]], branches, op_g)
                         new_bw_b = jnp.where(
                             valids[g] & blk(bw_dyn, offs[g]),
                             bw_new_b, bw_b)
@@ -508,9 +529,10 @@ def _make_worker(
                 decay_w = atd_decay[..., 0]                    # (B, 1)
                 w_off = jnp.where(do_r, w_off * decay_w, w_off)
                 w_on = jnp.where(do_r, w_on * decay_w, w_on)
-            return units, bw, w_off, w_on
+            return units, bw, w_off, w_on, trips
 
-        def step(carry, seg):
+        def step(carry_trips, seg):
+            carry, trips = carry_trips
             kind_k, acc_k, reconf_k = seg                      # (K,) each
             if any_policy:
                 (units, bw, pf, active, w_off, w_on, bw_acc, ipc_acc,
@@ -525,10 +547,11 @@ def _make_worker(
                        reconf_k & cache_dyn_k)
             if any_policy:
                 operand = operand + (ref_ipc, prev_ipc)
-            units, bw, w_off, w_on = jax.lax.cond(
+            units, bw, w_off, w_on, boundary_trips = jax.lax.cond(
                 jnp.any(reconf_k), reconfigure,
-                lambda op: (op[0], op[1], op[2], op[3]),
+                lambda op: (op[0], op[1], op[2], op[3], jnp.int32(0)),
                 operand)
+            trips = trips + boundary_trips
 
             # The A/B samples force the prefetcher off/on for everyone;
             # other segments run the current per-client setting.
@@ -586,7 +609,7 @@ def _make_worker(
                          ipc_acc, off_ipc)
             if any_policy:
                 new_carry = new_carry + (ref_ipc, prev_ipc)
-            return new_carry, None
+            return (new_carry, trips), None
 
         zeros = jnp.zeros((B, n), dtype=f64)
         carry0 = (rows(grid["units0"]), rows(grid["bw0"]),
@@ -595,12 +618,14 @@ def _make_worker(
         if any_policy:
             carry0 = carry0 + (zeros, zeros)
         xs = (mgr["kinds"].T, mgr["acc"].T, mgr["reconf"].T)   # (S, K)
-        carry, _ = jax.lax.scan(step, carry0, xs)
+        (carry, trips), _ = jax.lax.scan(step, (carry0, jnp.int32(0)), xs)
         units, bw, pf, active, _woff, _won, _bw_acc, ipc_acc, _off \
             = carry[:9]
-        return {k: v.reshape(K, M, n) for k, v in
-                {"ipc_acc": ipc_acc, "cache_units": units, "bandwidth": bw,
-                 "prefetch_on": pf, "active": active}.items()}
+        out = {k: v.reshape(K, M, n) for k, v in
+               {"ipc_acc": ipc_acc, "cache_units": units, "bandwidth": bw,
+                "prefetch_on": pf, "active": active}.items()}
+        out[_TRIPS_KEY] = trips.reshape(1, 1)
+        return out
 
     return worker
 
@@ -753,11 +778,15 @@ class PendingTimelines:
     """An in-flight stacked-timeline dispatch (asynchronous handle).
 
     The device program is already enqueued when this object exists;
-    ``device_results`` holds per-spec dicts of *device* arrays.  Nothing
-    blocks until :meth:`result` performs the device->host transfer, so a
-    caller can overlap host work (generating the next chunk of a stream)
-    with the device computing this one — the double-buffering contract of
-    :mod:`repro.sim.stream_sweep`.
+    ``outputs`` holds its per-worker dicts of *device* arrays (one worker
+    per length bucket).  Nothing blocks until :meth:`result` performs the
+    device->host transfer, so a caller can overlap host work (generating
+    the next chunk of a stream) with the device computing this one — the
+    double-buffering contract of :mod:`repro.sim.stream_sweep`.
+
+    ``device_results`` are the per-spec ``{field: (M, n)}`` device arrays,
+    sliced from ``outputs`` on first use: each slice is a small eager
+    device program of its own, outside the dispatch counter.
 
     ``donated_inputs`` (``donate=True`` dispatches only) are the device
     handles of the grid buffers handed to XLA: after the dispatch they are
@@ -765,16 +794,27 @@ class PendingTimelines:
     holding chunk c's grid alive while chunk c+1 transfers.
     """
 
-    device_results: List[dict]      # per-spec {field: (M, n) device array}
+    outputs: Tuple[dict, ...]
+    split: Callable[[Tuple[dict, ...]], List[dict]]
     w_accs: List[float]
     donated_inputs: Optional[List] = None
 
+    @functools.cached_property
+    def device_results(self) -> List[dict]:
+        # Sliced inside the x64 context: slicing a sharded float64 result
+        # is itself a traced program and must lower at the same precision
+        # the stacked program produced.
+        with x64_context():
+            return self.split(self.outputs)
+
     def block_until_ready(self) -> "PendingTimelines":
-        jax.block_until_ready([d for d in self.device_results])
+        jax.block_until_ready(self.outputs)
         return self
 
     def result(self) -> List[TimelineResult]:
-        """Blocking device->host transfer into :class:`TimelineResult`s."""
+        """Blocking device->host transfer into :class:`TimelineResult`s;
+        adds the programs' greedy trips to
+        :func:`repro.core.dispatch.greedy_trips`."""
         out = []
         for w_acc, dev in zip(self.w_accs, self.device_results):
             host = {k: np.asarray(v) for k, v in dev.items()}
@@ -786,7 +826,42 @@ class PendingTimelines:
                 prefetch_on=host["prefetch_on"],
                 active=host["active"],
             ))
+        # Fetched last: the slices above are enqueued behind the program
+        # while it runs, and fetching any of its outputs first would make
+        # the host wait for it before enqueuing them.
+        trips = jax.device_get([o[_TRIPS_KEY] for o in self.outputs])
+        record_greedy_trips(sum(int(t.sum()) for t in trips))
         return out
+
+
+@dataclasses.dataclass
+class StagedTimelines:
+    """A stacked-timeline program with every input built on the host and
+    not yet dispatched (:func:`stage_timelines`).  :meth:`dispatch`
+    enqueues it: one counted device program."""
+
+    fn: Callable
+    args: tuple                     # the program's non-donated arguments
+    carry: Optional[Any]            # host carry leaves to donate, or None
+    split: Callable[[Tuple[dict, ...]], List[dict]]
+    w_accs: List[float]
+
+    def dispatch(self) -> PendingTimelines:
+        record_dispatch()
+        donated = None
+        with x64_context():
+            if self.carry is not None:
+                # Stable device identities for the donated carry buffers:
+                # transfer first, keep the handles, and hand exactly those
+                # buffers to the program.  They are consumed by the dispatch
+                # (``is_deleted()`` afterwards) — the streaming smoke's gate.
+                carry = jax.device_put(self.carry)
+                donated = jax.tree_util.tree_leaves(carry)
+                out = self.fn(carry, *self.args)
+            else:
+                out = self.fn(*self.args)
+        outputs = out if isinstance(out, tuple) else (out,)
+        return PendingTimelines(outputs, self.split, self.w_accs, donated)
 
 
 def run_timelines(
@@ -821,8 +896,7 @@ def run_timelines(
         axes as needed); ``False`` forces single-device execution.
 
     Returns:
-      One :class:`TimelineResult` of host arrays per spec — the only
-      device->host transfer of all K timelines.
+      One :class:`TimelineResult` of host arrays per spec.
     """
     return run_timelines_async(
         apps, specs,
@@ -843,6 +917,32 @@ def run_timelines(
 def run_timelines_async(
     apps: Union[AppArrays, dict],
     specs: Sequence[TimelineSpec],
+    **kwargs,
+) -> PendingTimelines:
+    """:func:`run_timelines` without the blocking device->host transfer.
+
+    Dispatches the stacked program(s) and returns a
+    :class:`PendingTimelines` handle holding device arrays; call
+    ``.result()`` for the host-side :class:`TimelineResult`s.  Argument
+    semantics are identical to :func:`run_timelines` (which is literally
+    this followed by ``.result()``); it is :func:`stage_timelines`
+    followed by :meth:`StagedTimelines.dispatch`.
+
+    ``donate=True`` transfers the carry-state grid leaves (``units0`` /
+    ``bw0`` / ``pf0`` / ``active0``) to the device first and donates
+    exactly those buffers to the program — each aliases the final-state
+    output of identical shape/dtype, so a chunked stream
+    (:mod:`repro.sim.stream_sweep`) reuses chunk c's carry buffers for
+    chunk c's outputs instead of allocating fresh ones.  Donation changes
+    buffer *lifetime* only — results are bit-identical to the non-donated
+    path and the dispatch count is unchanged.
+    """
+    return stage_timelines(apps, specs, **kwargs).dispatch()
+
+
+def stage_timelines(
+    apps: Union[AppArrays, dict],
+    specs: Sequence[TimelineSpec],
     *,
     total_units: int,
     total_bandwidth: float,
@@ -855,24 +955,10 @@ def run_timelines_async(
     iters: int = FIXED_POINT_ITERS,
     shard: Optional[bool] = None,
     donate: bool = False,
-) -> PendingTimelines:
-    """:func:`run_timelines` without the blocking device->host transfer.
-
-    Dispatches the stacked program(s) and returns a
-    :class:`PendingTimelines` handle holding device arrays; call
-    ``.result()`` for the host-side :class:`TimelineResult`s.  Argument
-    semantics are identical to :func:`run_timelines` (which is literally
-    this followed by ``.result()``).
-
-    ``donate=True`` transfers the carry-state grid leaves (``units0`` /
-    ``bw0`` / ``pf0`` / ``active0``) to the device first and donates
-    exactly those buffers to the program — each aliases the final-state
-    output of identical shape/dtype, so a chunked stream
-    (:mod:`repro.sim.stream_sweep`) reuses chunk c's carry buffers for
-    chunk c's outputs instead of allocating fresh ones.  Donation changes
-    buffer *lifetime* only — results are bit-identical to the non-donated
-    path and the dispatch count is unchanged.
-    """
+) -> StagedTimelines:
+    """Everything :func:`run_timelines_async` does on the host before the
+    device call: feasibility checks, segment tables, the stacked grid and
+    the compiled program.  Arguments as in :func:`run_timelines`."""
     if not specs:
         raise ValueError("need at least one TimelineSpec")
     params = memsys_jax.app_params(apps)
@@ -951,7 +1037,7 @@ def run_timelines_async(
         # every slot of the longest table.  Only the mix axis may shard
         # here (all buckets then share one mesh over one device subset);
         # a sharded manager axis takes the single-bucket path below.
-        return _dispatch_buckets(
+        return _stage_buckets(
             buckets, tables, accum, grid, flags, replicated,
             K, M, grid_shards[1], int(total_units), int(iters), donate)
     kinds, acc, reconf = stack_tables(
@@ -977,41 +1063,30 @@ def run_timelines_async(
         max_realloc, int(total_units), int(iters), grid_shards, donate,
         any(s.cache_policy or s.bw_policy for s in specs),
         max(s.bandwidth_banks for s in specs))
-    record_dispatch()
-    donated = None
-    with x64_context():
-        if donate:
-            # Stable device identities for the donated carry buffers:
-            # transfer first, keep the handles, and hand exactly those
-            # buffers to the program.  They are consumed by the dispatch
-            # (``is_deleted()`` afterwards) — the streaming smoke's gate.
-            carry0 = jax.device_put({k: grid.pop(k) for k in _CARRY_KEYS})
-            donated = list(carry0.values())
-            res = fn(carry0, grid, mgr, replicated)
-        else:
-            res = fn(grid, mgr, replicated)
+    carry = ({k: grid.pop(k) for k in _CARRY_KEYS} if donate else None)
+
+    def split(outs):
         # Per-spec device-side slices: no transfer, no block — padding
-        # rows fall away exactly as the host-side [:K, :M] slice used to
-        # do.  Sliced inside the x64 context: slicing a sharded float64
-        # result is itself a traced program and must lower at the same
-        # precision the stacked program produced.
-        device_results = [{f: res[f][k, :M] for f in res}
-                          for k in range(K)]
-    w_accs = [float(a.sum()) for a in acc]
-    return PendingTimelines(device_results, w_accs, donated)
+        # rows fall away exactly as a host-side [:K, :M] slice would.
+        (res,) = outs
+        return [{f: v[k, :M] for f, v in res.items() if f != _TRIPS_KEY}
+                for k in range(K)]
+
+    return StagedTimelines(fn, (grid, mgr, replicated), carry, split,
+                           [float(a.sum()) for a in acc])
 
 
-def _dispatch_buckets(buckets, tables, accum, grid, flags, replicated,
-                      K: int, M: int, mix_shards: int,
-                      total_units: int, iters: int,
-                      donate: bool = False) -> PendingTimelines:
-    """Dispatch the stacked set as per-length bucket scans in ONE program.
+def _stage_buckets(buckets, tables, accum, grid, flags, replicated,
+                   K: int, M: int, mix_shards: int,
+                   total_units: int, iters: int,
+                   donate: bool = False) -> StagedTimelines:
+    """Stage the stacked set as per-length bucket scans in ONE program.
 
     Each bucket stacks only its own tables (:func:`stack_tables` snaps
     reconfigure slots within the bucket) and carries its own static knob
     summary, so e.g. the fully-static bucket drops the ATD precompute and
-    sampling machinery outright.  Returns a :class:`PendingTimelines`
-    whose per-spec device slices restore spec order.
+    sampling machinery outright.  The staged program's per-spec device
+    slices restore spec order.
     """
     m_pad = -(-M // mix_shards) * mix_shards
     statics = []
@@ -1041,26 +1116,20 @@ def _dispatch_buckets(buckets, tables, accum, grid, flags, replicated,
 
     fn = _compiled_buckets(tuple(statics), total_units, iters, mix_shards,
                            donate)
-    record_dispatch()
-    donated = None
-    with x64_context():
-        if donate:
-            # See run_timelines_async: transfer the carry leaves, keep
-            # the handles, donate exactly those.
-            carries = jax.device_put(tuple(
-                {k: g.pop(k) for k in _CARRY_KEYS} for g in bucket_grids))
-            donated = [v for c in carries for v in c.values()]
-            outs = fn(carries, tuple(bucket_grids), tuple(bucket_mgrs),
-                      replicated)
-        else:
-            outs = fn(tuple(bucket_grids), tuple(bucket_mgrs), replicated)
-        # Sliced inside the x64 context — see run_timelines_async.
+    carry = (tuple({k: g.pop(k) for k in _CARRY_KEYS} for g in bucket_grids)
+             if donate else None)
+
+    def split(outs):
         device_results: List[Optional[dict]] = [None] * K
         for idx_g, o in zip(buckets, outs):
             for row, i in enumerate(idx_g):
-                device_results[i] = {k: v[row, :M] for k, v in o.items()}
-    return PendingTimelines(device_results, [w_accs[i] for i in range(K)],
-                            donated)
+                device_results[i] = {k: v[row, :M] for k, v in o.items()
+                                     if k != _TRIPS_KEY}
+        return device_results
+
+    return StagedTimelines(
+        fn, (tuple(bucket_grids), tuple(bucket_mgrs), replicated), carry,
+        split, [w_accs[i] for i in range(K)])
 
 
 def run_timeline(
