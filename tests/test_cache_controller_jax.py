@@ -208,8 +208,63 @@ def _adversarial_refresh_curves(n, U):
     return curves
 
 
-def test_greedy_loop_trip_bound_never_abandons_live_rows():
-    """Satellite audit of the ``_greedy_loop`` trip bound.  The
+def _clamp_curves(n, U):
+    """Client 0 linear, every other client flat: the greedy hands client 0
+    every unit above the floors, one at a time, so its allocation reaches
+    the top column, where the curve shift clamps at the edge."""
+    curves = np.zeros((n, U + 1))
+    curves[0] = np.arange(U + 1, dtype=np.float64)
+    return curves
+
+
+def _greedy_case(name):
+    """``(curves, min_units, active, U)`` of one trajectory case."""
+    rng = np.random.default_rng(5)
+    if name == "adversarial":
+        n, U = 8, 96
+        curves = np.stack([
+            _adversarial_refresh_curves(n, U),
+            _nonmonotone_curves(np.random.default_rng(0), n, U),
+            np.zeros((n, U + 1)),
+            _concave_curves(np.random.default_rng(1), n, U),
+        ])
+        return curves, np.array([0, 3, 2, 1]), np.ones((4, n), bool), U
+    if name == "min_units_0":
+        n, U = 6, 64
+        curves = np.stack([_concave_curves(rng, n, U) for _ in range(3)])
+        return curves, np.zeros(3, int), np.ones((3, n), bool), U
+    if name == "clamp_top":
+        n, U = 5, 40
+        curves = np.stack([_clamp_curves(n, U), _concave_curves(rng, n, U)])
+        return curves, np.array([0, 2]), np.ones((2, n), bool), U
+    if name == "u96_nonmonotone":
+        n, U = 5, 96        # U + 1 = 97 columns: no power of two
+        curves = np.stack([_nonmonotone_curves(rng, n, U) for _ in range(2)])
+        return curves, np.array([1, 4]), np.ones((2, n), bool), U
+    if name == "inactive_row":
+        n, U = 6, 48
+        curves = np.stack([np.cumsum(np.abs(rng.normal(size=(n, U + 1))), 1)
+                           for _ in range(2)])
+        active = np.array([[True, False, True, True, False, True],
+                           [False] * n])
+        return curves, np.array([3, 2]), active, U
+    if name == "single_row":
+        n, U = 16, 256      # the sweep's client count and capacity
+        return (_concave_curves(rng, n, U)[None], np.array([4]),
+                np.ones((1, n), bool), U)
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("case, bodies", [
+    ("adversarial", 92),
+    ("min_units_0", 64),
+    ("clamp_top", 40),
+    ("u96_nonmonotone", 24),
+    ("inactive_row", 20),
+    ("single_row", 192),
+])
+def test_greedy_loop_trip_bound_never_abandons_live_rows(case, bodies):
+    """Audit of the ``_greedy_loop`` trip bound and its trajectory.  The
     incremental-refresh loop runs under an ``(n + 2) * U`` bound, which
     is safe: the greedy takes <= U unit-consuming steps per row, and
     between consecutive steps each of the n clients refreshes at most
@@ -219,28 +274,62 @@ def test_greedy_loop_trip_bound_never_abandons_live_rows():
     maximizes invalidations between steps; the loop must still exit with
     every row finished (balance drained or stuck), never via the bound —
     abandoning a live row would silently hand a short allocation to the
-    zero-spread tail."""
+    zero-spread tail.  The pinned body counts are those of the gathering
+    loop the gather-free one replaced: the same count is the same
+    trajectory.  The other cases hit the curve shift's edges: a zero
+    floor, an allocation at the top column (the clamp), a curve width
+    that is no power of two, a row with no active client, one row."""
     import jax.numpy as jnp
 
-    n, U = 8, 96
-    curves = np.stack([
-        _adversarial_refresh_curves(n, U),
-        _nonmonotone_curves(np.random.default_rng(0), n, U),
-        np.zeros((n, U + 1)),
-        _concave_curves(np.random.default_rng(1), n, U),
-    ])
-    mins = np.array([0, 3, 2, 1])
+    curves, mins, active, U = _greedy_case(case)
+    B, n, _ = curves.shape
+    remaining = U - mins * (n - active.sum(axis=-1))
     with x64_context():
         alloc, balance, stuck, it = map(np.asarray, ccj._greedy_loop(
             jnp.asarray(curves, jnp.float64), jnp.asarray(mins),
-            jnp.ones((4, n), dtype=bool),
-            jnp.full((4,), U, dtype=jnp.int32), total_units=U))
+            jnp.asarray(active), jnp.asarray(remaining, jnp.int32),
+            total_units=U))
+        # The shift the loop reads its gains through equals the clamped
+        # gather at the allocation it ended on.
+        shifted = np.asarray(ccj._shift_clamped(
+            jnp.asarray(curves, jnp.float64), jnp.asarray(alloc)))
+    cols = np.minimum(alloc[..., None] + np.arange(U + 1), U)
+    np.testing.assert_array_equal(
+        shifted, np.take_along_axis(curves, cols, axis=-1))
+    assert int(it) == bodies
     # The loop retired every row on its own terms, not via the bound.
     assert int(it) < (n + 2) * U
     assert np.all((balance == 0) | stuck)
     assert np.all(balance >= 0)
     # And the full pipeline (greedy + spread) still matches the golden.
-    got = ccj.lookahead_allocate(curves, U, mins)
-    for b in range(4):
-        np.testing.assert_array_equal(
-            got[b], lookahead_allocate(curves[b], U, int(mins[b])))
+    if active.all():
+        got = ccj.lookahead_allocate(curves, U, mins)
+        want = [lookahead_allocate(curves[b], U, int(mins[b]))
+                for b in range(B)]
+    else:
+        got = ccj.lookahead_allocate_masked(curves, U, mins, active)
+        want = [cppf_allocate(curves[b], U, int(mins[b]), active[b])
+                for b in range(B)]
+    for b in range(B):
+        np.testing.assert_array_equal(got[b], want[b])
+
+
+def test_greedy_loop_lowers_without_gathers():
+    """At the sweep's shape (56 rows, 16 clients, 256 units, float64) the
+    greedy's lowered program holds no gather and no dynamic slice: a TPU
+    reads those element by element, so a gathering body's cost grew with
+    its rows.  Guarded here on every platform, without a chip."""
+    import jax
+    import jax.numpy as jnp
+
+    B, n, U = 56, 16, 256
+    with x64_context():
+        text = ccj._greedy_loop.lower(
+            jax.ShapeDtypeStruct((B, n, U + 1), jnp.float64),
+            jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B, n), jnp.bool_),
+            jax.ShapeDtypeStruct((B,), jnp.int32),
+            total_units=U).as_text()
+    assert "stablehlo.while" in text
+    assert "stablehlo.gather" not in text
+    assert "stablehlo.dynamic_slice" not in text

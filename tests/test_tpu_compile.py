@@ -1,8 +1,9 @@
 """Ahead-of-time compiles of the Pallas kernels for a described TPU v5e.
 
-Each test lowers one kernel at the widths of a model the repo serves and
-compiles it with the TPU compiler for a ``v5e:2x2`` topology that is
-described, not attached: Mosaic refuses here what it would refuse on the
+Each test lowers one kernel at the widths of a model the repo serves (or,
+for the boundary greedy, at the evaluator sweep's shape) and compiles it
+with the TPU compiler for a ``v5e:2x2`` topology that is described, not
+attached: Mosaic refuses here what it would refuse on the
 chip (block shapes off the (8, 128) tiling, primitives it cannot lower,
 too much VMEM).  Nothing runs, so these say nothing about results or
 speed; the interpret-mode suites in ``test_kernels.py`` check results.
@@ -11,12 +12,16 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every test worker imports
 this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs
+from repro.core import cache_controller_jax as ccj
+from repro.core.x64 import x64_context
 from repro.kernels.cbp_matmul.kernel import cbp_matmul
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
 from repro.kernels.flash_decode.kernel import flash_decode
@@ -75,3 +80,26 @@ def test_ssd_scan_compiles_at_mamba2_1_3b_heads(one_chip):
     _compile(fn, [((1, s, h, p), jnp.bfloat16), ((1, s, h), jnp.float32),
                   ((h,), jnp.float32), ((1, s, n), jnp.bfloat16),
                   ((1, s, n), jnp.bfloat16)], one_chip)
+
+
+def test_lookahead_greedy_keeps_no_curve_gathers_at_sweep_shape(one_chip):
+    """The boundary greedy at the sweep's shape (56 rows, 16 clients, 256
+    units, float64), compiled for a v5e: the only gathers left are the
+    four ``(B, n)`` ones of ``_zero_spread``, outside the loop (the
+    gathering loop compiled to 44, 36 of them in its body)."""
+    B, n, U = 56, 16, 256
+
+    def fn(curves, mins, active, remaining):
+        return ccj._greedy_core(curves, mins, active, remaining,
+                                total_units=U)
+
+    shapes = [((B, n, U + 1), jnp.float64), ((B,), jnp.int32),
+              ((B, n), jnp.bool_), ((B,), jnp.int32)]
+    with x64_context():
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    assert " while(" in text
+    gathers = re.findall(r"= (\w+)\[([\d,]*)\]\S* gather\(", text)
+    assert len(gathers) <= 4
+    assert all(dims == f"{B},{n}" for _dtype, dims in gathers), gathers
