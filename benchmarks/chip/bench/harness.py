@@ -222,6 +222,12 @@ def run_cell(root: pathlib.Path, cell_name: str, seed: int, seconds: float,
         summary = trace_mod.summarize(trace_mod.load(
             trace_mod.find_xplane(str(trace_path))))
         shutil.rmtree(trace_path, ignore_errors=True)
+        if summary.cut:
+            print(f"run.py: the trace's device events stop "
+                  f"{window_s - summary.span_s:.2f} s short of the "
+                  f"{window_s:.2f} s window: its readings are short; "
+                  f"lower trace_seconds in workloads/{cell_name}.json",
+                  file=sys.stderr)
     else:
         record = entry.window(state, float(seconds), ctx)
     compiles_window = log.count - compiles_setup
@@ -259,7 +265,7 @@ def run_cell(root: pathlib.Path, cell_name: str, seed: int, seconds: float,
         "failed": int(failed), "metrics": metrics, "device": device,
         "window": {"compiles_in_window": compiles_window,
                    "compiles_in_setup": compiles_setup,
-                   "setup_s": setup_s},
+                   "setup_s": setup_s, "seconds": record["window_s"]},
     }
     if trace:
         device["busy_s"] = summary.busy_s

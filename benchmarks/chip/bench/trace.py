@@ -9,7 +9,12 @@ the same way.  It reads three things from the trace:
   with the ``(<id>)`` suffix removed;
 * the longest idle gaps between device operations, each attributed to the
   innermost host span that the benchmark opened around its own calls with
-  ``jax.profiler.TraceAnnotation("bench.<what>")``.
+  ``jax.profiler.TraceAnnotation("bench.<what>")``;
+* the span of the recorded device events, and whether they stop well
+  before the benchmark's host spans do: the profiler keeps a bounded
+  number of device events (a TPU v5e trace of the sweep cell kept 4,089
+  module and 6.2 million op executions, its first 5.0 s of an 8 s window),
+  and a window cut so reads short.
 
 A plane, line or event here is plain data, so the tests can hand-build a
 trace as well as read a recorded one.
@@ -54,6 +59,8 @@ class Summary:
     module_calls: Dict[str, int]
     op_s: Dict[str, float]             # summed over the devices
     gaps: List[Tuple[str, float]]      # longest first, device 0
+    span_s: float = 0.0                # recorded device events, mean
+    cut: bool = False                  # device events stop early
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -144,12 +151,25 @@ def module_name(name: str) -> str:
     return _MODULE_ID.sub("", name)
 
 
+def looks_cut(device_end_ns: float, spans: Sequence[Event]) -> bool:
+    """Whether the device events stop before the benchmark's host spans
+    end by more than a tenth of those spans' extent (and half a second):
+    the host's own work after the last device operation of a window is
+    far shorter."""
+    if not spans:
+        return False
+    start = min(s.start_ns for s in spans)
+    end = max(s.end_ns for s in spans)
+    return end - device_end_ns > max(0.5e9, 0.1 * (end - start))
+
+
 def summarize(planes: Sequence[Plane]) -> Summary:
     devs = device_planes(planes)
     if not devs:
         raise ValueError("the trace holds no device plane with XLA Ops")
     spans = host_spans(planes)
     busy_s, module_s, module_calls, op_s = [], {}, {}, {}
+    span_s, cut = [], False
     first_busy: List[Tuple[float, float]] = []
     for i, dev in enumerate(devs):
         ops = dev.lines["XLA Ops"]
@@ -157,6 +177,9 @@ def summarize(planes: Sequence[Plane]) -> Summary:
         if i == 0:
             first_busy = busy
         busy_s.append(sum(e - s for s, e in busy) * 1e-9)
+        if busy:
+            span_s.append((busy[-1][1] - busy[0][0]) * 1e-9)
+            cut = cut or looks_cut(busy[-1][1], spans)
         for e in ops:
             op_s[e.name] = op_s.get(e.name, 0.0) + e.dur_ns * 1e-9
         for e in dev.lines.get("XLA Modules", []):
@@ -170,6 +193,8 @@ def summarize(planes: Sequence[Plane]) -> Summary:
         module_calls=module_calls,
         op_s=op_s,
         gaps=gaps(first_busy, spans),
+        span_s=sum(span_s) / len(span_s) if span_s else 0.0,
+        cut=cut,
     )
 
 

@@ -1,5 +1,5 @@
 """Helpers for the chip benchmark's CPU tests: a temporary copy of the
-benchmark with a throwaway cell at a size the CPU can hold."""
+benchmark with throwaway cells at sizes the CPU can hold."""
 from __future__ import annotations
 
 import json
@@ -29,10 +29,39 @@ TINY_SWEEP = dict(
     trace_seconds=1)
 
 
+#: A dense decoder at the qwen3 smoke widths (the repository's
+#: ``configs.get_smoke("qwen3-8b")``), under the published names.
+TINY_LM = {"source": "test", "hidden_size": 64, "intermediate_size": 128,
+           "num_attention_heads": 4, "num_key_value_heads": 2,
+           "head_dim": 16, "num_hidden_layers": 2, "rms_norm_eps": 1e-6,
+           "rope_theta": 1e6, "vocab_size": 512, "qk_norm": True}
+
+
+def _tiny_chat():
+    """The chat mix with an eighth of its lengths, 6 conversations a
+    tenant."""
+    tr = json.loads((BENCH / "traffic" / "lmsys-chat-tenants.json")
+                    .read_text())
+    tr.update(conversations=6, prompt_mean=tr["prompt_mean"] / 8,
+              response_mean=tr["response_mean"] / 8)
+    return tr
+
+
+def _tiny_serve():
+    """The serving cell's own limits, at 8 slots and 96 steps a call, with
+    8 requests a tenant in the logit check."""
+    wl = json.loads((BENCH / "workloads" / "serve-chat-tenants.json")
+                    .read_text())
+    wl.update(max_steps=96, trace_seconds=1, sample_per_tenant=8)
+    wl["engine"].update(batch_slots=8, max_len=96, total_pages=48,
+                        reconfig_every_steps=8)
+    return wl
+
+
 def bench_copy(tmp: pathlib.Path) -> pathlib.Path:
     """A checkout-like root in ``tmp`` holding ``BENCHMARK.json`` and a
-    copy of the benchmark, with a throwaway cell ``tiny-sweep`` added as new
-    files only."""
+    copy of the benchmark, with throwaway cells ``tiny-sweep`` and
+    ``tiny-serve`` added as new files only."""
     root = tmp / "root"
     shutil.copytree(BENCH, root / "benchmarks" / "chip",
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -42,6 +71,9 @@ def bench_copy(tmp: pathlib.Path) -> pathlib.Path:
         b / "configs" / "tiny-cmp.json": TINY_CMP,
         b / "traffic" / "tiny-mixes.json": TINY_MIXES,
         b / "workloads" / "tiny-sweep.json": TINY_SWEEP,
+        b / "configs" / "tiny-lm.json": TINY_LM,
+        b / "traffic" / "tiny-chat.json": _tiny_chat(),
+        b / "workloads" / "tiny-serve.json": _tiny_serve(),
     }
     for path, obj in files.items():
         assert not path.exists(), path
@@ -49,12 +81,20 @@ def bench_copy(tmp: pathlib.Path) -> pathlib.Path:
     man["configs"].append(
         {"name": "tiny-cmp", "source": "test", "reduced": [], "why": "test",
          "file": "benchmarks/chip/configs/tiny-cmp.json"})
+    man["configs"].append(
+        {"name": "tiny-lm", "source": "test", "reduced": [], "why": "test",
+         "file": "benchmarks/chip/configs/tiny-lm.json"})
     man["workloads"].append(
         {"name": "tiny-sweep", "config": "tiny-cmp", "traffic": "tiny-mixes",
          "chips": 1, "why": "test"})
+    man["workloads"].append(
+        {"name": "tiny-serve", "config": "tiny-lm", "traffic": "tiny-chat",
+         "chips": 1, "why": "test"})
     for m in man["end_to_end"] + man["per_layer"]:
-        if "sweep-table2" in m.get("workloads", ()):
-            m["workloads"].append("tiny-sweep")
+        for real, tiny in (("sweep-table2", "tiny-sweep"),
+                           ("serve-chat-tenants", "tiny-serve")):
+            if real in m.get("workloads", ()):
+                m["workloads"].append(tiny)
     (root / "BENCHMARK.json").write_text(json.dumps(man))
     return root
 

@@ -68,6 +68,22 @@ def test_gaps_longest_first_named_by_innermost_span():
     assert [g for _, g in s.gaps] == pytest.approx([380e-9, 100e-9])
 
 
+@pytest.mark.parametrize("device_end_s, cut", [(9.9, False), (5.0, True)])
+def test_summary_flags_device_events_that_stop_early(device_end_s, cut):
+    """The device events' span, and a cut when they stop well before the
+    benchmark's host spans end (a trace that kept only its first 5 s)."""
+    host = trace.Plane("/host:CPU", {"python": [
+        _ev("bench.window", 0, 10e9), _ev("bench.call", 1e9, 8e9)]})
+    dev = trace.Plane("/device:TPU:0", {
+        "XLA Ops": [_ev("fusion.1", 0.5e9, 1e9),
+                    _ev("fusion.2", device_end_s * 1e9 - 1e9, 1e9)],
+        "XLA Modules": [_ev("jit_step(1)", 0.5e9, device_end_s * 1e9
+                            - 0.5e9)]})
+    s = trace.summarize([host, dev])
+    assert s.span_s == pytest.approx(device_end_s - 0.5)
+    assert s.cut is cut
+
+
 def test_summary_needs_a_device_plane():
     with pytest.raises(ValueError):
         trace.summarize([_planes()[0]])
