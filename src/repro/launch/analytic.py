@@ -25,7 +25,7 @@ def analytic_memory(cfg, spec, chips: int, optimizer: str) -> Dict:
     if spec.kind == "decode":
         sites = cfg.n_layers
         if cfg.family == "hybrid":
-            sites = (cfg.n_layers + cfg.attn_every - 1) // cfg.attn_every
+            sites = len(cfg.hybrid_layer_ids)
         kv = 0.0
         if cfg.family not in ("ssm",):
             kv = (2.0 * sites * spec.global_batch * spec.seq_len
@@ -36,7 +36,7 @@ def analytic_memory(cfg, spec, chips: int, optimizer: str) -> Dict:
         if cfg.family in ("ssm", "hybrid"):
             state = (4.0 * cfg.n_layers * spec.global_batch
                      * (cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state
-                        + (cfg.ssm_conv - 1) * cfg.d_inner))
+                        + (cfg.ssm_conv - 1) * cfg.conv_dim))
         out["kv_cache_bytes"] = (kv + state) / chips
     out["total_bytes"] = float(sum(out.values()))
     return out

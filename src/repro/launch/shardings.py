@@ -1,10 +1,10 @@
 """PartitionSpec assignment for parameters, optimizer state, caches and
-batches (DESIGN.md §4).
+batches.
 
 Rules are *leaf-name based* and rank-aware so the same table covers stacked
 (``(L, ...)``) and unstacked (hybrid shared block) parameters:
 
-  wq / wg / wu / wi / wx / wz / wdt  -> shard LAST dim over "model"
+  wq / wg / wu / wi / wxbc / wz / wdt -> shard LAST dim over "model"
         (query heads / d_ff / ssm channels; column-parallel)
   wo / wd / out                      -> shard dim -2 over "model"
         (row-parallel: contraction dim sharded, output partial-summed)
@@ -15,7 +15,9 @@ Rules are *leaf-name based* and rank-aware so the same table covers stacked
   A_log / D / dt_bias / norm (rank 2)-> shard last (ssm heads/channels)
 
 Batches shard over the DP axes; decode KV caches shard the *sequence* dim
-over "model" (split-KV decode) and SSM states shard heads.
+over "model" (split-KV decode) and SSM states shard their channels (heads
+x head_dim).  ``wxbc`` is x, B and C side by side, sharded as one block:
+see ``repro.models.ssm`` for the reshard that costs on a mesh.
 """
 from __future__ import annotations
 
@@ -28,7 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.models.config import ModelConfig
 from repro.optim.optimizers import OptState
 
-LAST = {"wg", "wu", "wi", "wx", "wz", "wdt", "embed"}
+LAST = {"wg", "wu", "wi", "wxbc", "wz", "wdt", "embed"}
 ROW = {"wo", "wd", "out"}
 REPL = {"wk", "wv", "router", "ln", "ln1", "ln2", "lnx", "q_norm",
         "k_norm", "final_norm", "enc_norm", "dt_bias_repl"}
@@ -204,16 +206,18 @@ def cache_specs(cfg: ModelConfig, cache_shape: Dict, mesh) -> Dict:
     m = _mdl(mesh) if not cfg.pure_dp else None
 
     def one(path, leaf):
-        name = path[-1].key if hasattr(path[-1], "key") else ""
+        # the hybrid's per-site K/V are lists: the name is the list's key
+        keys = [k.key for k in path if hasattr(k, "key")]
+        name = keys[-1] if keys else ""
         if leaf.ndim == 0:
             return P()
         spec = [None] * leaf.ndim
         b = leaf.shape[1] if leaf.ndim > 1 else 0
         spec[1] = _best_dp_subset(mesh, cfg, b) if b else None
         if name in ("k", "v", "xk", "xv") and leaf.ndim == 5:
-            spec[2] = m              # (L, B, Smax, Hkv, Dh): shard sequence
+            spec[2] = m              # (L|1, B, Smax, Hkv, Dh): sequence
         elif name == "state" and leaf.ndim == 5:
-            spec[2] = m              # (L, B, H, P, N): shard ssm heads
+            spec[4] = m              # (L, B, G, N, Hg*P): shard channels
         elif name == "conv" and leaf.ndim == 4:
             spec[3] = m              # (L, B, K-1, di): shard channels
         return P(*spec)

@@ -36,7 +36,8 @@ def repeat_kv(k: jnp.ndarray, n_rep: int) -> jnp.ndarray:
 
 def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      chunk: int = 1024, causal: bool = True,
-                     remat_chunk: bool = True) -> jnp.ndarray:
+                     remat_chunk: bool = True,
+                     scale: Optional[float] = None) -> jnp.ndarray:
     """Chunked attention.  q: (B, Sq, H, Dh); k/v: (B, Sk, H, Dh).
 
     Scores are computed q-chunk at a time so the live score buffer is
@@ -46,7 +47,7 @@ def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     """
     b, sq, h, dh = q.shape
     sk = k.shape[1]
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
     chunk = min(chunk, sq)
     n_chunks = max(sq // chunk, 1)
     rem = sq - n_chunks * chunk
@@ -91,8 +92,8 @@ def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 
 def decode_attention_gqa(q: jnp.ndarray, k_cache: jnp.ndarray,
-                         v_cache: jnp.ndarray, cur_len: jnp.ndarray
-                         ) -> jnp.ndarray:
+                         v_cache: jnp.ndarray, cur_len: jnp.ndarray,
+                         scale: Optional[float] = None) -> jnp.ndarray:
     """Grouped-query decode WITHOUT materializing repeated KV.
 
     q: (B, 1, Hq, Dh); caches: (B, Smax, Hkv, Dh), Hq = G * Hkv (kv-major
@@ -103,7 +104,7 @@ def decode_attention_gqa(q: jnp.ndarray, k_cache: jnp.ndarray,
     b, one, hq, dh = q.shape
     hkv = k_cache.shape[2]
     g = hq // hkv
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
     qg = q.reshape(b, hkv, g, dh)
     scores = jnp.einsum(
         "bkgd,bskd->bkgs", qg, k_cache).astype(jnp.float32) * scale
@@ -116,8 +117,8 @@ def decode_attention_gqa(q: jnp.ndarray, k_cache: jnp.ndarray,
 
 
 def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
-                     v_cache: jnp.ndarray, cur_len: jnp.ndarray
-                     ) -> jnp.ndarray:
+                     v_cache: jnp.ndarray, cur_len: jnp.ndarray,
+                     scale: Optional[float] = None) -> jnp.ndarray:
     """Single-position attention against a (possibly seq-sharded) cache.
 
     q: (B, 1, H, Dh); k_cache/v_cache: (B, Smax, H, Dh); cur_len: () or (B,)
@@ -125,7 +126,7 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     all-reduce pair when Smax is sharded over the model axis.
     """
     dh = q.shape[-1]
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
     scores = jnp.einsum(
         "bqhd,bshd->bhqs", q, k_cache).astype(jnp.float32) * scale
     smax = k_cache.shape[1]

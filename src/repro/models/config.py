@@ -36,9 +36,14 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_head_dim: int = 64
     ssm_chunk: int = 128
+    ssm_ngroups: int = 1      # B/C groups; heads split evenly among them
 
-    # Hybrid (zamba2-style): shared attention block applied every k layers
-    attn_every: int = 0
+    # Hybrid (Zamba2): ``num_mem_blocks`` shared attention+MLP blocks, used
+    # in turn at the layers ``hybrid_layer_ids``, each site with its own
+    # rank-``adapter_rank`` MLP adapter and output linear
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 1
+    adapter_rank: int = 0
 
     # Enc-dec (whisper): n_layers == decoder layers
     n_enc_layers: int = 0
@@ -113,6 +118,11 @@ class ModelConfig:
         return self.d_inner // self.ssm_head_dim
 
     @property
+    def conv_dim(self) -> int:
+        """Channels of the Mamba2 causal conv: x, B and C."""
+        return self.d_inner + 2 * self.ssm_ngroups * self.ssm_state
+
+    @property
     def n_rep(self) -> int:
         return self.padded_heads // self.n_kv_heads
 
@@ -139,7 +149,9 @@ class ModelConfig:
         elif self.family == "ssm":
             total = L * self._mamba_block_params()
         elif self.family == "hybrid":
-            total = L * self._mamba_block_params() + (attn + mlp + norms)
+            total = (L * self._mamba_block_params()
+                     + self.num_mem_blocks * self._shared_block_params()
+                     + len(self.hybrid_layer_ids) * self._site_params())
         elif self.family == "encdec":
             enc = self.n_enc_layers * (attn + mlp + norms)
             dec = L * (2 * attn + mlp + 3 * d)
@@ -151,11 +163,25 @@ class ModelConfig:
         return total
 
     def _mamba_block_params(self) -> int:
-        d, di = self.d_model, self.d_inner
-        n, hh = self.ssm_state, self.ssm_heads
-        # in projections (z, x, B, C, dt) + conv + A/D + gated norm + out
-        return (d * (2 * di + 2 * n + hh) + di * self.ssm_conv
-                + 2 * hh + di + di * d + d)
+        d, di, c = self.d_model, self.d_inner, self.conv_dim
+        hh = self.ssm_heads
+        # input norm + in projection (z, xBC, dt) + conv and its bias over
+        # xBC + dt_bias/A_log/D + gated norm + out projection
+        return (d + d * (di + c + hh) + c * (self.ssm_conv + 1) + 3 * hh
+                + di + di * d)
+
+    def _shared_block_params(self) -> int:
+        """A Zamba2 shared block: norm over [h, e], attention from 2 d,
+        norm, GELU-gated MLP."""
+        d, f, dh = self.d_model, self.d_ff, self.head_dim
+        hq, hkv = self.n_heads * dh, self.n_kv_heads * dh
+        return (2 * d + 2 * d * (hq + 2 * hkv) + hq * d + d + d * 2 * f
+                + f * d)
+
+    def _site_params(self) -> int:
+        """A Zamba2 site's own weights: the MLP adapter and the linear."""
+        d, r = self.d_model, self.adapter_rank
+        return d * r + r * 2 * self.d_ff + d * d
 
     def active_param_count(self) -> int:
         """Activated parameters per token (MoE: top_k of n_experts)."""

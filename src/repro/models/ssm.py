@@ -8,15 +8,23 @@ runs over chunks (sequential inter-chunk state) and the per-chunk math is
 batched matmuls; on real TPU hardware the per-chunk body is the Pallas
 kernel in ``repro.kernels.ssd_scan`` and this jnp path is its oracle.
 
+The block is the published Mamba2 mixer: one input projection to z, xBC
+and dt; a causal depthwise conv with a bias over xBC (x and the B/C of
+``ssm_ngroups`` groups, heads split evenly among the groups), then SiLU;
+the SSD recurrence; an RMSNorm of ``y * SiLU(z)`` over each group's
+channels; the output projection.
+
 Sharding: SSD heads are sharded over the "model" axis (64 heads for
-mamba2-1.3b, 112 for zamba2-7b — both divisible by 16); B/C projections are
-group-shared (n_groups=1) and replicated; the conv is depthwise over the
-head-sharded channel dim, so the whole block is comm-free except the
-in/out projections' boundary collectives.
+mamba2-1.3b, 112 for zamba2-7b — both divisible by 16), and so are the
+conv's channels.  The input projection's xBC columns are sharded as one
+block (``wxbc``), so the cut between x and B/C falls inside a shard: on a
+mesh, besides the in/out projections' boundary collectives, every layer
+reshards x and gathers B and C.  Splitting ``wxbc`` into an x and a B/C
+leaf would remove that; no cell runs the block on more than one chip.
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,30 +33,31 @@ from repro.distributed import constrain
 from repro.models import layers as L
 from repro.models.config import ModelConfig
 
+F32 = jnp.float32
+
 
 def _dtype(cfg: ModelConfig):
     return jnp.dtype(cfg.param_dtype)
 
 
 def init_mamba(key, cfg: ModelConfig, n_layers: int) -> Dict:
-    d, di = cfg.d_model, cfg.d_inner
-    n, h, k = cfg.ssm_state, cfg.ssm_heads, cfg.ssm_conv
+    d, di, c = cfg.d_model, cfg.d_inner, cfg.conv_dim
+    h, k = cfg.ssm_heads, cfg.ssm_conv
     dt = _dtype(cfg)
-    ks = jax.random.split(key, 8)
+    ks = jax.random.split(key, 6)
     return {
-        "wx": L.dense_init(ks[0], (n_layers, d, di), dt, in_axis=1),
-        "wz": L.dense_init(ks[1], (n_layers, d, di), dt, in_axis=1),
-        "wB": L.dense_init(ks[2], (n_layers, d, n), dt, in_axis=1),
-        "wC": L.dense_init(ks[3], (n_layers, d, n), dt, in_axis=1),
-        "wdt": L.dense_init(ks[4], (n_layers, d, h), dt, in_axis=1),
-        "dt_bias": jnp.zeros((n_layers, h), dt),
-        "A_log": jnp.zeros((n_layers, h), jnp.float32),
-        "D": jnp.ones((n_layers, h), dt),
-        "conv": (jax.random.normal(ks[5], (n_layers, di, k), jnp.float32)
-                 * (1.0 / k)).astype(dt),
-        "norm": jnp.ones((n_layers, di), dt),
-        "out": L.dense_init(ks[6], (n_layers, di, d), dt, in_axis=1),
         "ln": jnp.ones((n_layers, d), dt),
+        "wz": L.dense_init(ks[0], (n_layers, d, di), dt, in_axis=1),
+        "wxbc": L.dense_init(ks[1], (n_layers, d, c), dt, in_axis=1),
+        "wdt": L.dense_init(ks[2], (n_layers, d, h), dt, in_axis=1),
+        "conv": (jax.random.normal(ks[3], (n_layers, c, k), F32)
+                 * (1.0 / k)).astype(dt),
+        "conv_b": jnp.zeros((n_layers, c), dt),
+        "dt_bias": jnp.zeros((n_layers, h), dt),
+        "A_log": jnp.zeros((n_layers, h), F32),
+        "D": jnp.ones((n_layers, h), dt),
+        "norm": jnp.ones((n_layers, di), dt),
+        "out": L.dense_init(ks[4], (n_layers, di, d), dt, in_axis=1),
     }
 
 
@@ -70,121 +79,193 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int,
                 initial_state=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Chunked SSD scan (pure-jnp oracle).
 
-    x: (B, S, H, P); dt: (B, S, H); A: (H,) negative; Bm/Cm: (B, S, N).
-    Returns (y (B,S,H,P), final_state (B,H,P,N)).
+    x: (B, S, H, P); dt: (B, S, H); A: (H,) negative; Bm/Cm: (B, S, G, N)
+    for G groups (heads split evenly, in order, among them) or (B, S, N)
+    for one.  Returns (y (B,S,H,P), final_state (B,H,P,N)).
     """
+    if Bm.ndim == 3:
+        Bm, Cm = Bm[:, :, None], Cm[:, :, None]
     b, s, h, p = x.shape
-    n = Bm.shape[-1]
+    g, n = Bm.shape[2], Bm.shape[3]
+    hg = h // g
     cl = min(chunk, s)
     assert s % cl == 0, (s, cl)
     nc = s // cl
 
-    xr = x.reshape(b, nc, cl, h, p).astype(jnp.float32)
-    dtr = dt.reshape(b, nc, cl, h).astype(jnp.float32)
-    Br = Bm.reshape(b, nc, cl, n).astype(jnp.float32)
-    Cr = Cm.reshape(b, nc, cl, n).astype(jnp.float32)
-    dA = dtr * A[None, None, None, :]               # (B,nc,cl,H) log-decay
-    cs = jnp.cumsum(dA, axis=2)                     # inclusive cumsum
+    xr = x.reshape(b, nc, cl, g, hg, p).astype(F32)
+    dtr = dt.reshape(b, nc, cl, g, hg).astype(F32)
+    Br = Bm.reshape(b, nc, cl, g, n).astype(F32)
+    Cr = Cm.reshape(b, nc, cl, g, n).astype(F32)
+    dA = dtr * A.reshape(g, hg)                      # log-decay
+    cs = jnp.cumsum(dA, axis=2)                      # inclusive cumsum
 
-    xdt = xr * dtr[..., None]                       # dt-weighted inputs
+    xdt = xr * dtr[..., None]                        # dt-weighted inputs
 
     if initial_state is None:
-        initial_state = jnp.zeros((b, h, p, n), jnp.float32)
+        initial_state = jnp.zeros((b, h, p, n), F32)
+    causal = jnp.tril(jnp.ones((cl, cl)))[None, :, :, None, None]
 
     def chunk_body(state, inputs):
-        xc, dAc, csc, Bc, Cc = inputs  # (B,cl,H,P) (B,cl,H) (B,cl,H) ...
+        xc, csc, Bc, Cc = inputs   # (B,cl,G,Hg,P) (B,cl,G,Hg) (B,cl,G,N) x2
         # Intra-chunk ("diag block"): M[i,j] = (C_i.B_j) exp(cs_i-cs_j), j<=i
-        G = jnp.einsum("bin,bjn->bij", Cc, Bc)      # (B,cl,cl)
-        decay = jnp.exp(csc[:, :, None, :] - csc[:, None, :, :])  # (B,i,j,H)
-        causal = jnp.tril(jnp.ones((xc.shape[1], xc.shape[1])))
-        M = G[:, :, :, None] * decay * causal[None, :, :, None]
-        y_intra = jnp.einsum("bijh,bjhp->bihp", M, xc)
+        Gm = jnp.einsum("bign,bjgn->bijg", Cc, Bc)
+        decay = jnp.exp(csc[:, :, None] - csc[:, None, :])  # (B,i,j,G,Hg)
+        M = Gm[..., None] * decay * causal
+        y_intra = jnp.einsum("bijgh,bjghp->bighp", M, xc)
         # Contribution of the carried state: exp(cs_i) C_i . state
-        sdec = jnp.exp(csc)                          # (B,cl,H)
-        y_inter = jnp.einsum("bin,bhpn,bih->bihp", Cc, state, sdec)
+        y_inter = jnp.einsum("bign,bghpn,bigh->bighp", Cc, state,
+                             jnp.exp(csc))
         # Next state: chunk-end decay of current + new outer products
-        edec = jnp.exp(csc[:, -1:, :] - csc)         # decay j..end (B,cl,H)
-        new_state = jnp.einsum("bjn,bjhp,bjh->bhpn", Bc, xc, edec)
-        state = (jnp.exp(csc[:, -1, :])[:, :, None, None] * state
-                 + new_state)
+        edec = jnp.exp(csc[:, -1:] - csc)            # decay j..end
+        new_state = jnp.einsum("bjgn,bjghp,bjgh->bghpn", Bc, xc, edec)
+        state = (jnp.exp(csc[:, -1])[..., None, None] * state + new_state)
         return state, y_intra + y_inter
 
-    inputs = (
-        xdt.transpose(1, 0, 2, 3, 4),
-        dA.transpose(1, 0, 2, 3),
-        cs.transpose(1, 0, 2, 3),
-        Br.transpose(1, 0, 2, 3),
-        Cr.transpose(1, 0, 2, 3),
-    )
-    final_state, ys = jax.lax.scan(chunk_body, initial_state, inputs)
-    y = ys.transpose(1, 0, 2, 3, 4).reshape(b, s, h, p)
-    return y.astype(x.dtype), final_state
+    inputs = tuple(a.swapaxes(0, 1) for a in (xdt, cs, Br, Cr))
+    final_state, ys = jax.lax.scan(
+        chunk_body, initial_state.reshape(b, g, hg, p, n), inputs)
+    y = ys.swapaxes(0, 1).reshape(b, s, h, p)
+    return y.astype(x.dtype), final_state.reshape(b, h, p, n)
 
 
-def mamba_block(p, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
-    """One Mamba2 block (train/prefill).  x: (B, S, d)."""
+def gated_norm(y, z, scale, groups: int, eps: float):
+    """RMSNorm of ``y * SiLU(z)`` over each of ``groups`` equal channel
+    groups, with float32 statistics."""
+    v = y.astype(F32) * jax.nn.silu(z.astype(F32))
+    shape = v.shape
+    v = v.reshape(shape[:-1] + (groups, shape[-1] // groups))
+    v = v * jax.lax.rsqrt(jnp.mean(v * v, -1, keepdims=True) + eps)
+    return (v.reshape(shape) * scale.astype(F32)).astype(y.dtype)
+
+
+def _split_xbc(cfg: ModelConfig, xbc):
+    """x, B and C of the conv's output, B and C as (..., G, N)."""
+    di, g, n = cfg.d_inner, cfg.ssm_ngroups, cfg.ssm_state
+    lead = xbc.shape[:-1]
+    return (xbc[..., :di], xbc[..., di: di + g * n].reshape(lead + (g, n)),
+            xbc[..., di + g * n:].reshape(lead + (g, n)))
+
+
+def mamba_block(p, cfg: ModelConfig, x: jnp.ndarray,
+                t: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """One Mamba2 layer (train/prefill): x + Mamba2(RMSNorm(x + t)), with
+    ``t`` (the Zamba2 shared block's output) entering the input only.
+    x: (B, S, d)."""
     b, s, d = x.shape
-    h = L.rms_norm(x, p["ln"], cfg.norm_eps)
-    xi = jnp.einsum("bsd,de->bse", h, p["wx"])       # (B,S,di)
+    h = L.rms_norm(x if t is None else x + t, p["ln"], cfg.norm_eps)
     z = jnp.einsum("bsd,de->bse", h, p["wz"])
-    Bm = jnp.einsum("bsd,dn->bsn", h, p["wB"])
-    Cm = jnp.einsum("bsd,dn->bsn", h, p["wC"])
+    xbc = jnp.einsum("bsd,de->bse", h, p["wxbc"])
     dt_raw = jnp.einsum("bsd,dh->bsh", h, p["wdt"])
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
-                         + p["dt_bias"].astype(jnp.float32))
-    xi = causal_conv(xi, p["conv"])
-    xi = jax.nn.silu(xi)
+    dt = jax.nn.softplus(dt_raw.astype(F32) + p["dt_bias"].astype(F32))
+    xbc = jax.nn.silu(causal_conv(xbc, p["conv"])
+                      + p["conv_b"].astype(xbc.dtype))
+    xi, Bm, Cm = _split_xbc(cfg, xbc)
     xi = constrain(xi, "dp", None, "model")
     hh, pp = cfg.ssm_heads, cfg.ssm_head_dim
-    A = -jnp.exp(p["A_log"])
-    y, _ = ssd_chunked(
-        xi.reshape(b, s, hh, pp), dt, A, Bm, Cm, cfg.ssm_chunk)
-    y = y + xi.reshape(b, s, hh, pp) * p["D"][None, None, :, None].astype(y.dtype)
-    y = y.reshape(b, s, cfg.d_inner)
-    y = L.rms_norm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
+    A = -jnp.exp(p["A_log"].astype(F32))
+    y, _ = ssd_chunked(xi.reshape(b, s, hh, pp), dt, A, Bm, Cm,
+                       cfg.ssm_chunk)
+    y = y + xi.reshape(b, s, hh, pp) * p["D"][None, None, :, None].astype(
+        y.dtype)
+    y = gated_norm(y.reshape(b, s, cfg.d_inner), z, p["norm"],
+                   cfg.ssm_ngroups, cfg.norm_eps)
     out = jnp.einsum("bse,ed->bsd", y, p["out"])
     return x + out
 
 
 def init_ssm_cache(cfg: ModelConfig, batch: int, n_layers: int,
-                   dtype=jnp.float32) -> Dict:
+                   dtype=F32) -> Dict:
+    """Per layer and row: the last K-1 xBC inputs, and the SSM state as
+    (groups, state, heads of the group x head_dim): the last axis is the
+    group's x channels in order, so the state is as wide as its lanes and
+    the step reads and writes it in the layout it computes in."""
+    g = cfg.ssm_ngroups
     return {
-        "conv": jnp.zeros((n_layers, batch, cfg.ssm_conv - 1, cfg.d_inner),
+        "conv": jnp.zeros((n_layers, batch, cfg.ssm_conv - 1, cfg.conv_dim),
                           dtype),
-        "state": jnp.zeros((n_layers, batch, cfg.ssm_heads,
-                            cfg.ssm_head_dim, cfg.ssm_state), dtype),
+        "state": jnp.zeros((n_layers, batch, g, cfg.ssm_state,
+                            cfg.d_inner // g), dtype),
     }
 
 
-def mamba_decode(p, cfg: ModelConfig, x, conv_state, ssm_state):
-    """One-token Mamba2 step.  x: (B, 1, d).  Returns (out, new_conv,
-    new_state)."""
+def start_fresh(fresh, conv_state, ssm_state):
+    """The recurrent state with the rows that start a sequence (``fresh``,
+    (B,)) taken as zero: a slot that takes a new request must not carry
+    its last request's conv window and SSM state.  An elementwise select
+    that XLA fuses into the step's own read of the state, so it costs no
+    pass over the state of its own."""
+    keep = ~fresh
+    return (jnp.where(keep[:, None, None], conv_state, 0),
+            jnp.where(keep[:, None, None, None], ssm_state, 0))
+
+
+def mamba_decode(p, cfg: ModelConfig, x, conv_state, ssm_state, fresh=None,
+                 t=None):
+    """One-token Mamba2 step.  x: (B, 1, d); conv_state (B, K-1, C) holds
+    the last K-1 xBC inputs; ssm_state (B, G, N, Hg*P) as
+    :func:`init_ssm_cache` lays it out; ``fresh`` (B,) marks the rows at
+    position 0, which start from a zero window and state; ``t`` as in
+    :func:`mamba_block`.  Returns (out, new_conv, new_state)."""
     b = x.shape[0]
-    h = L.rms_norm(x, p["ln"], cfg.norm_eps)[:, 0]   # (B, d)
-    xi = h @ p["wx"]
+    if fresh is not None:
+        conv_state, ssm_state = start_fresh(fresh, conv_state, ssm_state)
+    h = L.rms_norm(x if t is None else x + t, p["ln"], cfg.norm_eps)[:, 0]
     z = h @ p["wz"]
-    Bm = (h @ p["wB"]).astype(jnp.float32)           # (B, N)
-    Cm = (h @ p["wC"]).astype(jnp.float32)
-    dt = jax.nn.softplus((h @ p["wdt"]).astype(jnp.float32)
-                         + p["dt_bias"].astype(jnp.float32))  # (B, H)
-    # conv ring: conv_state (B, K-1, di) holds the previous inputs.
+    xbc = h @ p["wxbc"]
+    dt = jax.nn.softplus((h @ p["wdt"]).astype(F32)
+                         + p["dt_bias"].astype(F32))          # (B, H)
     window = jnp.concatenate(
-        [conv_state, xi[:, None, :].astype(conv_state.dtype)], axis=1)
-    conv_out = jnp.einsum("bkc,ck->bc", window, p["conv"].astype(jnp.float32))
+        [conv_state, xbc[:, None, :].astype(conv_state.dtype)], axis=1)
+    conv_out = (jnp.einsum("bkc,ck->bc", window, p["conv"].astype(F32))
+                + p["conv_b"].astype(F32))
     new_conv = window[:, 1:, :]
-    xi = jax.nn.silu(conv_out)                       # (B, di)
-    hh, pp = cfg.ssm_heads, cfg.ssm_head_dim
-    xh = xi.reshape(b, hh, pp).astype(jnp.float32)
-    A = -jnp.exp(p["A_log"])
-    decay = jnp.exp(dt * A[None, :])                 # (B, H)
-    new_state = (decay[:, :, None, None] * ssm_state
-                 + jnp.einsum("bhp,bn,bh->bhpn", xh, Bm, dt))
-    y = jnp.einsum("bn,bhpn->bhp", Cm, new_state)
-    y = y + xh * p["D"].astype(jnp.float32)[None, :, None]
+    xi, Bm, Cm = _split_xbc(cfg, jax.nn.silu(conv_out))
+    g, pp = cfg.ssm_ngroups, cfg.ssm_head_dim
+    xg = xi.reshape(b, g, -1).astype(F32)                    # (B, G, Hg*P)
+
+    def per_channel(v):       # (B, H) or (H,) per head -> per x channel
+        return jnp.repeat(v, pp, axis=-1).reshape(v.shape[:-1] + (g, -1))
+
+    A = -jnp.exp(p["A_log"].astype(F32))
+    decay = per_channel(jnp.exp(dt * A))                     # (B, G, Hg*P)
+    new_state = (decay[:, :, None, :] * ssm_state
+                 + Bm[..., None] * (per_channel(dt) * xg)[:, :, None, :])
+    y = jnp.einsum("bgn,bgnk->bgk", Cm, new_state)
+    y = y + xg * per_channel(p["D"].astype(F32))
     y = y.reshape(b, cfg.d_inner).astype(x.dtype)
-    y = L.rms_norm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
+    y = gated_norm(y, z, p["norm"], g, cfg.norm_eps)
     out = (y @ p["out"])[:, None, :]                 # (B, 1, d)
     return x + out, new_conv, new_state
+
+
+def decode_layers(layers, cfg: ModelConfig, x, conv, state, lo: int,
+                  hi: int, fresh, t=None):
+    """Mamba layers ``lo .. hi - 1`` of a one-token step.  ``conv`` and
+    ``state`` are the stacks of every layer, (L, B, ...), carried whole:
+    each layer's window and state are read and written in place, so a step
+    holds one copy of the recurrent state.  ``t`` (a range of one layer
+    only) as in :func:`mamba_decode`.  Returns (x, conv, state)."""
+    if hi <= lo:
+        return x, conv, state
+
+    def body(i, carry):
+        x, conv, state = carry
+        lp = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+            layers)
+        x, c, s = mamba_decode(
+            lp, cfg, x, jax.lax.dynamic_index_in_dim(conv, i, keepdims=False),
+            jax.lax.dynamic_index_in_dim(state, i, keepdims=False), fresh, t)
+        return (x, jax.lax.dynamic_update_index_in_dim(conv, c, i, 0),
+                jax.lax.dynamic_update_index_in_dim(state, s, i, 0))
+
+    return jax.lax.fori_loop(lo, hi, body, (x, conv, state))
+
+
+def row_positions(cur_len, batch: int):
+    """``cur_len`` (a scalar or per row) as a (B,) int32 vector."""
+    return jnp.broadcast_to(
+        jnp.reshape(jnp.asarray(cur_len, jnp.int32), (-1,)), (batch,))
 
 
 def loss_fn(params, cfg: ModelConfig, batch) -> jnp.ndarray:
@@ -216,16 +297,13 @@ def init_params(key, cfg: ModelConfig) -> Dict:
 
 
 def decode_step(params, cfg: ModelConfig, cache, tokens, cur_len):
+    """One greedy decode step; rows at position 0 start from a zero
+    recurrent state."""
     from repro.models import transformer as T
     x = T.embed(params, cfg, tokens)
-
-    def body(x, lp_cache):
-        lp, cs, ss = lp_cache
-        x, nc, ns = mamba_decode(lp, cfg, x, cs, ss)
-        return x, (nc, ns)
-
-    x, (nc, ns) = jax.lax.scan(
-        body, x, (params["layers"], cache["conv"], cache["state"]))
+    fresh = row_positions(cur_len, x.shape[0]) == 0
+    x, conv, state = decode_layers(params["layers"], cfg, x, cache["conv"],
+                                   cache["state"], 0, cfg.n_layers, fresh)
     hidden = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = T.logits_fn(params, cfg, hidden)
-    return logits, {"conv": nc, "state": ns}
+    return logits, {"conv": conv, "state": state}

@@ -120,8 +120,10 @@ def _project_qkv(p, cfg: ModelConfig, h):
 
 
 def attention_block(p, cfg: ModelConfig, x, positions,
-                    causal: bool = True) -> jnp.ndarray:
-    """Full-sequence attention (train / prefill)."""
+                    causal: bool = True,
+                    scale: Optional[float] = None) -> jnp.ndarray:
+    """Full-sequence attention (train / prefill); ``scale`` defaults to
+    head_dim ** -0.5."""
     b, s, d = x.shape
     q, k, v = _project_qkv(p, cfg, x)
     if cfg.rope_theta > 0:
@@ -131,13 +133,16 @@ def attention_block(p, cfg: ModelConfig, x, positions,
     q = constrain(q, "dp", None, hq, None)
     k = repeat_kv(k, cfg.n_rep)
     v = repeat_kv(v, cfg.n_rep)
-    o = causal_attention(q, k, v, chunk=cfg.attn_chunk, causal=causal)
+    o = causal_attention(q, k, v, chunk=cfg.attn_chunk, causal=causal,
+                         scale=scale)
     o = constrain(o, "dp", None, hq, None)
     return jnp.einsum("bsk,kd->bsd", o.reshape(b, s, -1), p["wo"])
 
 
-def attention_decode(p, cfg: ModelConfig, x, cache_k, cache_v, cur_len):
+def attention_decode(p, cfg: ModelConfig, x, cache_k, cache_v, cur_len,
+                     scale: Optional[float] = None):
     """One-token attention against the cache; returns (out, new_k, new_v).
+    ``scale`` defaults to head_dim ** -0.5.
 
     cache_k/v: (B, Smax, Hkv, Dh), sequence-sharded over "model".
     ``cur_len`` is either a scalar () — every row writes/attends at the
@@ -205,11 +210,11 @@ def attention_decode(p, cfg: ModelConfig, x, cache_k, cache_v, cur_len):
     cvd = _kv_load(cfg, cache_v)
     if cfg.decode_gqa == "grouped" and cfg.n_rep > 1:
         from repro.models.attention import decode_attention_gqa
-        o = decode_attention_gqa(q, ckd, cvd, write_at + 1)
+        o = decode_attention_gqa(q, ckd, cvd, write_at + 1, scale)
     else:
         ck = repeat_kv(ckd, cfg.n_rep)
         cv = repeat_kv(cvd, cfg.n_rep)
-        o = decode_attention(q, ck, cv, write_at + 1)
+        o = decode_attention(q, ck, cv, write_at + 1, scale)
     if not cfg.pure_dp:
         o = constrain(o, bax, None, None, None)
     out = jnp.einsum("bsk,kd->bsd", o.reshape(b, 1, -1), p["wo"])

@@ -26,6 +26,9 @@ scheduling is deterministic —
     request id (wall-clock timestamps made Algorithm 1 nondeterministic,
     and ``t_in if t_in else ...`` misfired on the falsy-but-valid zeroth
     tick and on re-admission);
+  * a slot that takes a new request decodes it from position 0, where
+    the model's decode step zeroes any recurrent state (conv window and
+    SSM state of the ``ssm`` and ``hybrid`` families) of that row;
   * the token-bucket admission pick is a per-STREAM deficit argmax with a
     lowest-stream-index tie-break, then FIFO within the winning stream
     (was a first-come scan over the pending list, i.e. the tie-break

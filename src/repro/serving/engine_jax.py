@@ -36,6 +36,17 @@ Algorithm-2 demand-vs-prefetch split as the host pool, but is a proxy,
 not a bit-mirror, of the LRU stack (tokens and scheduling do not depend
 on it).
 
+Recurrent state (the ``ssm`` and ``hybrid`` families): the model's
+decode step zeroes the conv window and SSM state of every row at position
+0, inside that step, so a slot that takes a new request never starts from
+the last request's state.  The recurrent state is a fixed charge per slot,
+outside the page partition, which covers K/V tokens only.
+
+After ``run()`` the engine keeps what the last step left in the slots:
+``cache`` (the model's cache, freed when the next run starts), and per slot
+``slot_request`` (the index of its request in the run's list, -1 where the
+slot is empty) and ``slot_pos`` (the tokens its request has been fed).
+
 Scaling: ``n_groups`` splits streams/slots/pages into independent engine
 shards laid out on a 2-D grid and sharded with
 :func:`repro.distributed.shard_grid`; the KV cache shards its slot axis
@@ -143,6 +154,7 @@ class JitServingEngine:
         self.steps = 0
         self.reconfigs = 0
         self.intervals = 0
+        self.cache = None
 
     # ------------------------------------------------------------- #
     # state construction (host side, once per run)
@@ -550,6 +562,7 @@ class JitServingEngine:
         per reconfiguration interval."""
         if not requests:
             return requests
+        self.cache = None           # the last run's, freed before this one's
         state = self._build_state(requests)
         ms = jnp.int32(min(max_steps, np.iinfo(np.int32).max))
         n_intervals = max(1, math.ceil(max_steps / self._chunk))
@@ -575,6 +588,12 @@ class JitServingEngine:
             return q[name].reshape(-1)  # stream s = g * npg + s_local
 
         self.nonfinite_logits = int(q["nonfinite_logits"].sum())
+        self.cache = state["kv"]
+        req_at = {loc: i for i, loc in self._req_loc.items()}
+        self.slot_request = np.array(
+            [req_at[(g, int(r))] if a else -1 for g in range(len(q["active"]))
+             for r, a in zip(q["slot_req"][g], q["active"][g])])
+        self.slot_pos = flat("pos").astype(np.int64)
         self.steps = int(q["steps"].max())
         self.reconfigs = int(q["reconfigs"].max())
         self.slot_share = flat("slot_share").astype(np.float64)
