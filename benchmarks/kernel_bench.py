@@ -45,13 +45,13 @@ def flash_decode_bench() -> None:
     rng = jax.random.PRNGKey(1)
     ks = jax.random.split(rng, 3)
     q = jax.random.normal(ks[0], (4, 8, 64))
-    kc = jax.random.normal(ks[1], (4, 8, 2048, 64))
-    vc = jax.random.normal(ks[2], (4, 8, 2048, 64))
+    kc = jax.random.normal(ks[1], (1, 4, 2048, 8, 64))
+    vc = jax.random.normal(ks[2], (1, 4, 2048, 8, 64))
     with timer() as t:
-        out_full = flash_decode(q, kc, vc, jnp.asarray(2048), block_kv=256,
-                                interpret=True)
-        out_short = flash_decode(q, kc, vc, jnp.asarray(128), block_kv=256,
-                                 interpret=True)
+        out_full = flash_decode(q, kc, vc, 0, jnp.asarray(2048),
+                                block_kv=256, interpret=True)
+        out_short = flash_decode(q, kc, vc, 0, jnp.asarray(128),
+                                 block_kv=256, interpret=True)
     emit("kernel_flash_decode", t.seconds, {
         "kv2048_finite": bool(np.isfinite(np.asarray(out_full)).all()),
         "short_len_skips_blocks": "cur_len=128 -> 15/16 kv blocks skipped",
@@ -155,9 +155,11 @@ def kernel_block_plan_bench() -> None:
     bmat = jax.random.normal(ks[1], (512, 512), jnp.float32)
     q, k, v = (jax.random.normal(s, (1, 4, 512, 64), jnp.float32)
                for s in ks[2:5])
-    dq = jax.random.normal(ks[5], (4, 8, 64))
-    kc = jax.random.normal(ks[6], (4, 8, 2048, 64))
-    vc = jax.random.normal(ks[7], (4, 8, 2048, 64))
+    # Each of 4 x 8 heads its own row of one KV head, so a block is the
+    # planner's (block_kv, head_dim) KV tile.
+    dq = jax.random.normal(ks[5], (32, 1, 64))
+    kc = jax.random.normal(ks[6], (1, 32, 2048, 1, 64))
+    vc = jax.random.normal(ks[7], (1, 32, 2048, 1, 64))
     b, s, h, p, n = 1, 512, 4, 16, 32
     x = jax.random.normal(ks[8], (b, s, h, p))
     dt = jax.nn.softplus(jax.random.normal(ks[9], (b, s, h))) * 0.5
@@ -180,7 +182,7 @@ def kernel_block_plan_bench() -> None:
                                            interpret=True, **kw),
             lambda: attention_ref(q, k, v, causal=True)),
         "flash_decode": (
-            lambda kw: flash_decode(dq, kc, vc, jnp.asarray(2048),
+            lambda kw: flash_decode(dq, kc, vc, 0, jnp.asarray(2048),
                                     interpret=True, **kw),
             None),  # reference = the default-block run
         "ssd_scan": (

@@ -1,18 +1,18 @@
-"""Pure-jnp oracle for single-token decode attention."""
+"""Pure-jnp oracle for flash decode: the XLA grouped-query decode over the
+layer's slice of the stacked cache."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.models.attention import decode_attention_gqa
+
 
 def decode_ref(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
-               cur_len: int) -> jnp.ndarray:
-    """q: (B, H, Dh); caches: (B, H, Smax, Dh); attend to [0, cur_len)."""
-    dh = q.shape[-1]
-    s = jnp.einsum("bhd,bhsd->bhs", q, k_cache).astype(jnp.float32)
-    s = s * (dh ** -0.5)
-    smax = k_cache.shape[2]
-    mask = jnp.arange(smax)[None, None, :] < cur_len
-    s = jnp.where(mask, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhs,bhsd->bhd", p.astype(v_cache.dtype), v_cache)
+               layer, lengths) -> jnp.ndarray:
+    """q: (B, Hq, Dh); caches: (L, B, Smax, Hkv, Dh); attend row b to
+    positions [0, lengths[b]) (``lengths`` () or (B,))."""
+    k = jax.lax.dynamic_index_in_dim(k_cache, layer, 0, False)
+    v = jax.lax.dynamic_index_in_dim(v_cache, layer, 0, False)
+    lens = jnp.broadcast_to(jnp.asarray(lengths), q.shape[:1])
+    return decode_attention_gqa(q[:, None], k, v, lens)[:, 0]

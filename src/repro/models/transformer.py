@@ -23,7 +23,8 @@ import jax.numpy as jnp
 
 from repro.distributed import constrain
 from repro.models import layers as L
-from repro.models.attention import causal_attention, decode_attention, repeat_kv
+from repro.models.attention import (causal_attention, decode_attention,
+                                    decode_attention_gqa, repeat_kv)
 from repro.models.config import ModelConfig
 
 
@@ -139,6 +140,17 @@ def attention_block(p, cfg: ModelConfig, x, positions,
     return jnp.einsum("bsk,kd->bsd", o.reshape(b, s, -1), p["wo"])
 
 
+def _decode_qkv(p, cfg: ModelConfig, x, cur_len):
+    """The projection and RoPE of one-token decode: (q, k, v), each
+    (B, 1, heads, Dh), rotated to ``cur_len`` (() or (B,))."""
+    q, k, v = _project_qkv(p, cfg, x)   # (B, 1, H*, Dh)
+    if cfg.rope_theta > 0:
+        pos = jnp.reshape(cur_len, (-1,))[:, None]  # (B|1, 1)
+        q = L.apply_rope(q, pos, cfg.rope_theta)
+        k = L.apply_rope(k, pos, cfg.rope_theta)
+    return q, k, v
+
+
 def attention_decode(p, cfg: ModelConfig, x, cache_k, cache_v, cur_len,
                      scale: Optional[float] = None):
     """One-token attention against the cache; returns (out, new_k, new_v).
@@ -152,11 +164,7 @@ def attention_decode(p, cfg: ModelConfig, x, cache_k, cache_v, cur_len,
     DUSes, while the scatter writes exactly B rows.
     """
     b = x.shape[0]
-    q, k, v = _project_qkv(p, cfg, x)   # (B, 1, H*, Dh)
-    if cfg.rope_theta > 0:
-        pos = jnp.reshape(cur_len, (-1,))[:, None]  # (B|1, 1)
-        q = L.apply_rope(q, pos, cfg.rope_theta)
-        k = L.apply_rope(k, pos, cfg.rope_theta)
+    q, k, v = _decode_qkv(p, cfg, x, cur_len)
     per_row = jnp.ndim(cur_len) >= 1
     if per_row:
         # Per-row scatter: touches B rows instead of masking the whole
@@ -209,7 +217,6 @@ def attention_decode(p, cfg: ModelConfig, x, cache_k, cache_v, cur_len,
     ckd = _kv_load(cfg, cache_k)
     cvd = _kv_load(cfg, cache_v)
     if cfg.decode_gqa == "grouped" and cfg.n_rep > 1:
-        from repro.models.attention import decode_attention_gqa
         o = decode_attention_gqa(q, ckd, cvd, write_at + 1, scale)
     else:
         ck = repeat_kv(ckd, cfg.n_rep)
@@ -405,30 +412,112 @@ def _kv_load(cfg: ModelConfig, cache):
     return cache
 
 
+def _decode_ffn(lp, cfg: ModelConfig, x):
+    h = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
+    if cfg.family == "moe":
+        return x + moe_ffn(lp["moe"], cfg, h)
+    return x + L.swiglu(h, lp["mlp"]["wg"], lp["mlp"]["wu"],
+                        lp["mlp"]["wd"])
+
+
+# Positions a block of the decode kernel reads: it divides the serving
+# cell's 576 positions into three.
+DECODE_BLOCK_KV = 192
+
+
+def _kernel_reads_layer(cfg: ModelConfig, dtype) -> bool:
+    """Whether the per-row decode reads each layer through
+    ``kernels.flash_decode``.  The kernel takes a row's positions and KV
+    heads as one (Smax * Hkv, Dh) plane.  A TPU tiles the cache's
+    (Hkv, Dh) slabs by (Hkv, 128), ``4 // itemsize`` rows to a 32-bit
+    word, and the plane by (8, 128): the two hold their bytes in the same
+    order, so the plane is a bitcast, when Hkv is a whole number of words
+    and divides 8 or is a multiple of it.  Compiled for a described v5e,
+    bfloat16 caches with 2, 4, 8 or 16 KV heads and int8 ones with 4, 8 or
+    16 bitcast; bfloat16 with 1, 6 or 12 and int8 with 2 copy the whole
+    stacked cache in every layer, so those keep the XLA attention."""
+    hkv, per_word = cfg.n_kv_heads, max(4 // jnp.dtype(dtype).itemsize, 1)
+    return hkv % per_word == 0 and (hkv % 8 == 0 or 8 % hkv == 0)
+
+
+def _decode_layers_rows(params, cfg: ModelConfig, cache, x, cur_len):
+    """The layer scan of a decode step with every row at its own position
+    (``cur_len`` of shape (B,)).
+
+    The stacked caches (L, B, Smax, Hkv, Dh) ride in the scan's carry and
+    the layer index is its input: each layer scatters its B new rows into
+    the carry in place, then attends over its own layer of it.  Where
+    ``_kernel_reads_layer`` holds, ``kernels.flash_decode`` reads the layer
+    where it lies, and only up to each row's length; otherwise XLA's
+    grouped attention copies the layer's slice out first (one pass over
+    the whole cache a step).  As the scan's xs and ys, the caches would be
+    sliced out a layer at a time and stacked into a fresh buffer that the
+    serving engine copies back into its carried state: three passes over
+    the whole cache a step to add B rows a layer.  The serving engines,
+    the only callers of this form, hold their caches unsharded or sharded
+    over the slot axis and set no ambient mesh, so nothing here is pinned
+    to one; the attention is always the grouped one (``cfg.decode_gqa``
+    acts on the scalar form alone).
+    """
+    b = x.shape[0]
+    write_at = jnp.asarray(cur_len, jnp.int32).reshape(-1)     # (B,)
+    rows = jnp.arange(b)
+    if _kernel_reads_layer(cfg, cache["k"].dtype):
+        # Imported here: Pallas takes about a second to import, which
+        # models that never run the kernel (the hybrid's sites) need not
+        # pay.
+        from repro.kernels.flash_decode.ops import flash_decode
+
+        kv_scale = KV_INT8_SCALE if cfg.kv_cache_dtype == "int8" else None
+
+        def attend(q, ck, cv, i):
+            return flash_decode(q[:, 0], ck, cv, i, write_at + 1,
+                                block_kv=DECODE_BLOCK_KV, kv_scale=kv_scale)
+    else:
+        def attend(q, ck, cv, i):
+            return decode_attention_gqa(q, _kv_load(cfg, ck[i]),
+                                        _kv_load(cfg, cv[i]), write_at + 1)
+
+    def body(carry, xs):
+        x, ck, cv = carry
+        lp, i = xs
+        h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
+        q, k, v = _decode_qkv(lp["attn"], cfg, h, write_at)
+        ck = ck.at[i, rows, write_at].set(_kv_store(cfg, k, ck)[:, 0])
+        cv = cv.at[i, rows, write_at].set(_kv_store(cfg, v, cv)[:, 0])
+        o = attend(q, ck, cv, i)
+        x = x + jnp.einsum("bsk,kd->bsd", o.reshape(b, 1, -1),
+                           lp["attn"]["wo"])
+        return (_decode_ffn(lp, cfg, x), ck, cv), None
+
+    (x, ck, cv), _ = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(cfg.n_layers)))
+    return x, {"k": ck, "v": cv}
+
+
 def decode_step(params, cfg: ModelConfig, cache, tokens, cur_len):
     """One greedy decode step.  tokens: (B, 1) int32 (or embeddings
-    (B, 1, d) for stub frontends); cur_len: () current cache length.
-    Returns (logits, new_cache)."""
+    (B, 1, d) for stub frontends); cur_len: () current cache length, or
+    (B,) per row (``_decode_layers_rows``).  Returns (logits, new_cache)."""
     if tokens.ndim == 3:
         x = tokens.astype(_dtype(cfg))
     else:
         x = embed(params, cfg, tokens)
 
-    def body(x, lp_and_cache):
-        lp, ck, cv = lp_and_cache
-        h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        att, nk, nv = attention_decode(lp["attn"], cfg, h, ck, cv, cur_len)
-        x = x + att
-        h = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.family == "moe":
-            x = x + moe_ffn(lp["moe"], cfg, h)
-        else:
-            x = x + L.swiglu(h, lp["mlp"]["wg"], lp["mlp"]["wu"],
-                             lp["mlp"]["wd"])
-        return x, (nk, nv)
+    if jnp.ndim(cur_len) >= 1:
+        x, new_cache = _decode_layers_rows(params, cfg, cache, x, cur_len)
+    else:
+        def body(x, lp_and_cache):
+            lp, ck, cv = lp_and_cache
+            h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
+            att, nk, nv = attention_decode(lp["attn"], cfg, h, ck, cv,
+                                           cur_len)
+            return _decode_ffn(lp, cfg, x + att), (nk, nv)
 
-    x, (nk, nv) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
+        x, (nk, nv) = jax.lax.scan(
+            body, x, (params["layers"], cache["k"], cache["v"]))
+        new_cache = {"k": nk, "v": nv}
     hidden = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = logits_fn(params, cfg, hidden)
-    return logits, {"k": nk, "v": nv}
+    return logits, new_cache

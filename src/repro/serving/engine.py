@@ -34,8 +34,9 @@ scheduling is deterministic —
     (was a first-come scan over the pending list, i.e. the tie-break
     depended on interleaving).
 
-On-CPU tests drive it with tiny models; the decode step is the same jitted
-``model.decode_step`` the dry-run lowers for the production mesh.
+On-CPU tests drive it with tiny models; the decode step is
+``model.decode_step`` in its per-row form, jitted with the cache donated
+so that each step writes its rows into the one cache in place.
 """
 from __future__ import annotations
 
@@ -91,7 +92,7 @@ class ServingEngine:
         self.pool = PagedKVPool(self.cfg.total_pages, n_streams)
         self.kv = model.init_cache(self.cfg.batch_slots, self.cfg.max_len,
                                    dtype=jnp.dtype(model.cfg.kv_cache_dtype))
-        self._decode = jax.jit(model.decode_step)
+        self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
         # CBP state
         self.slot_share = np.full(n_streams,
                                   self.cfg.batch_slots / n_streams)

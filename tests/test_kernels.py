@@ -82,18 +82,22 @@ def test_flash_attention_property(s_blocks, h, seed):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("smax,cur_len", [(256, 256), (256, 100),
-                                          (512, 1), (512, 511)])
+                                          (512, 1), (512, 511), (320, 300)])
 def test_flash_decode_matches_ref(dtype, smax, cur_len):
+    """Grouped heads (8 query heads over 4 KV heads), one layer of a
+    stacked cache, rows at lengths ``cur_len`` and about half of it; with
+    320 positions the last 128-position block runs past the cache."""
     rng = jax.random.PRNGKey(2)
-    b, h, d = 2, 4, 64
+    n_layers, b, hkv, g, d = 2, 2, 4, 2, 64
     ks = jax.random.split(rng, 3)
-    q = jax.random.normal(ks[0], (b, h, d), jnp.float32).astype(dtype)
-    kc = jax.random.normal(ks[1], (b, h, smax, d), jnp.float32).astype(dtype)
-    vc = jax.random.normal(ks[2], (b, h, smax, d), jnp.float32).astype(dtype)
-    out = flash_decode(q, kc, vc, jnp.asarray(cur_len, jnp.int32),
-                       block_kv=128, interpret=True)
+    q = jax.random.normal(ks[0], (b, hkv * g, d), jnp.float32).astype(dtype)
+    kc, vc = (jax.random.normal(k, (n_layers, b, smax, hkv, d),
+                                jnp.float32).astype(dtype) for k in ks[1:])
+    lens = jnp.asarray([cur_len, max(cur_len // 2, 1)], jnp.int32)
+    out = flash_decode(q, kc, vc, jnp.int32(1), lens, block_kv=128,
+                       interpret=True)
     ref = decode_ref(q.astype(jnp.float32), kc.astype(jnp.float32),
-                     vc.astype(jnp.float32), cur_len)
+                     vc.astype(jnp.float32), 1, lens)
     np.testing.assert_allclose(
         out.astype(jnp.float32), ref, atol=TOL[dtype], rtol=TOL[dtype])
 
@@ -105,13 +109,13 @@ def test_flash_decode_ignores_cache_tail():
     b, h, smax, d = 1, 2, 256, 32
     ks = jax.random.split(rng, 3)
     q = jax.random.normal(ks[0], (b, h, d))
-    kc = jax.random.normal(ks[1], (b, h, smax, d))
-    vc = jax.random.normal(ks[2], (b, h, smax, d))
-    out1 = flash_decode(q, kc, vc, jnp.asarray(77), block_kv=64,
+    kc = jax.random.normal(ks[1], (1, b, smax, h, d))
+    vc = jax.random.normal(ks[2], (1, b, smax, h, d))
+    out1 = flash_decode(q, kc, vc, 0, jnp.asarray(77), block_kv=64,
                         interpret=True)
     kc2 = kc.at[:, :, 77:].set(1e6)
     vc2 = vc.at[:, :, 77:].set(-1e6)
-    out2 = flash_decode(q, kc2, vc2, jnp.asarray(77), block_kv=64,
+    out2 = flash_decode(q, kc2, vc2, 0, jnp.asarray(77), block_kv=64,
                         interpret=True)
     np.testing.assert_allclose(out1, out2, atol=1e-6)
 
